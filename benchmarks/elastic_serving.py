@@ -80,8 +80,8 @@ def run_elastic_cell(cell: str, model_bytes: float, duration: float,
 
 def jax_migration_cell(n_new: int = 8) -> Dict:
     """Drive the real MigrationProtocol: golden uninterrupted gang-2 run vs a
-    gang-4 run shrunk to 2 mid-stream on the SAME physical devices, for the
-    exact and the replay KV hand-off modes."""
+    gang-4 run on four devices shrunk to the golden's two mid-stream, for
+    the exact and the replay KV hand-off modes."""
     from repro.distributed.elastic_serving import ensure_host_devices
     ensure_host_devices(4)              # no-op once jax is initialised
     import jax
@@ -94,7 +94,7 @@ def jax_migration_cell(n_new: int = 8) -> Dict:
 
     cfg = get_config("qwen2.5-3b", smoke=True)
     params = init_params(jax.random.PRNGKey(0), cfg)
-    devs = jax.devices()[:2]
+    devs = jax.devices()[:4]
 
     def reqs():
         rng = np.random.default_rng(3)
@@ -107,8 +107,8 @@ def jax_migration_cell(n_new: int = 8) -> Dict:
             rep.add(r)
         return {r.id: list(r.generated) for r in rep.run()}
 
-    golden = run_all(ElasticReplica(cfg, params, 2, n_slots=2, devices=devs),
-                     reqs())
+    golden = run_all(ElasticReplica(cfg, params, 2, n_slots=2,
+                                    devices=devs[:2]), reqs())
     out: Dict = {"n_devices": len(jax.devices())}
     for mode in ("migrate", "replay"):
         rep = ElasticReplica(cfg, params, 4, n_slots=2, kv_mode=mode,
@@ -118,10 +118,12 @@ def jax_migration_cell(n_new: int = 8) -> Dict:
             rep.add(r)
         for _ in range(4):
             rep.step()
+        mesh_before = rep.mesh_size
         rec = rep.shrink(2)
         got = run_all(rep, [])
         out[mode] = {
             "tokens_equal": got == golden,
+            "mesh_sizes": [mesh_before, rep.mesh_size],
             "n_migrations": len(rep.migrations),
             "migrated_bytes": rep.migrated_bytes,
             "wire_bytes": rep.wire_bytes,

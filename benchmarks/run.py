@@ -114,6 +114,8 @@ def main() -> None:
             sys.exit(2)
         benches = {args.only: benches[args.only]}
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     all_detail = {}
     n_errors = 0
     print("name,us_per_call,derived")

@@ -14,6 +14,7 @@ _EXPORTS = {
     "ElasticReplica": "repro.distributed.elastic_serving",
     "MigrationProtocol": "repro.distributed.elastic_serving",
     "MigrationRecord": "repro.distributed.elastic_serving",
+    "ambient_mesh_sizes": "repro.distributed.sharding",
     "cache_shardings": "repro.distributed.sharding",
     "input_shardings": "repro.distributed.sharding",
     "maybe_shard": "repro.distributed.sharding",
@@ -25,6 +26,7 @@ __all__ = [
     "ElasticReplica",
     "MigrationProtocol",
     "MigrationRecord",
+    "ambient_mesh_sizes",
     "cache_shardings",
     "input_shardings",
     "maybe_shard",

@@ -22,34 +22,21 @@ def _axis(mesh: Mesh, name: str) -> int:
 
 
 def ambient_mesh_sizes() -> Optional[Dict[str, int]]:
-    """Axis-name -> size of the ambient mesh (the ``with mesh:`` context the
-    launcher established), or None when no mesh is active. Public-API lookup,
-    version-guarded like the ``jax.sharding.AxisType`` gate in
-    ``launch.mesh``: ``jax.sharding.get_abstract_mesh`` where it exists
-    (post-0.4.x), else the long-stable ``jax.interpreters.pxla`` re-export of
-    ``thread_resources`` — never ``jax._src``."""
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        am = get_am()
-        if am is not None and not getattr(am, "empty", True):
-            return dict(zip(am.axis_names, am.axis_sizes))
-    try:
-        from jax.interpreters import pxla
-        pm = pxla.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
+    """Axis-name -> size of the ambient mesh (the ``jax.set_mesh`` context
+    the launcher established), or None when no mesh is active."""
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
         return None
-    if pm.empty:
-        return None
-    return dict(zip(pm.axis_names, pm.devices.shape))
+    return dict(zip(am.axis_names, am.axis_sizes))
 
 
 def maybe_shard(x, *axes):
     """Best-effort activation sharding constraint: applies
-    ``with_sharding_constraint`` against the AMBIENT mesh (the ``with mesh:``
-    context the launcher established). Axes unknown to the mesh or larger than
-    the dimension are dropped; with no ambient mesh this is the identity —
-    so model code can call it unconditionally and still run in plain CPU
-    tests."""
+    ``with_sharding_constraint`` against the AMBIENT mesh (the
+    ``jax.set_mesh`` context the launcher established). Axes unknown to the
+    mesh or larger than the dimension are dropped; with no ambient mesh this
+    is the identity — so model code can call it unconditionally and still
+    run in plain CPU tests."""
     sizes = ambient_mesh_sizes()
     if sizes is None:
         return x

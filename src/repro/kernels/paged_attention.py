@@ -8,12 +8,15 @@ sequence's block table. The table is passed as a *scalar-prefetch* operand
 grid step — the gather costs no extra HBM traffic and the (B, S, KV, D)
 dense view is never materialized.
 
-Grid: (batch, kv_head, block) executed row-major, so the innermost axis
-walks a sequence's blocks in order and the online-softmax running stats
-(m, l, acc) persist in VMEM scratch, exactly like the flash kernel. Each
-program handles the whole G = H // KV query-head group for its kv head
-(decode has a single query position, so the group is the natural tile).
-Blocks fully past ``context_lens[b]`` are skipped via ``pl.when``.
+Grid: (batch, block) executed row-major, so the innermost axis walks a
+sequence's blocks in order and the online-softmax running stats (m, l, acc)
+persist in VMEM scratch, exactly like the flash kernel. Each step DMAs one
+whole ``(BS, KV, D)`` block — the trailing ``(KV, D)`` dims equal the pool's,
+which is what the TPU's (8, 128) block-tiling rule accepts for any head
+count and head dim — and loops over the KV heads inside the kernel, each
+handling its whole G = H // KV query-head group (decode has a single query
+position, so the group is the natural tile). Blocks fully past
+``context_lens[b]`` are skipped via ``pl.when``.
 """
 from __future__ import annotations
 
@@ -30,8 +33,9 @@ NEG_INF = -1e9
 def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, scale: float, block_size: int):
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
+    n_kv = q_ref.shape[1]
 
     @pl.when(j == 0)
     def _init():
@@ -43,24 +47,26 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j * block_size < ctx)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)        # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)     # (BS, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)     # (BS, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (G, BS)
-        pos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < ctx, s, NEG_INF)
-        m_prev = m_scr[...]                        # (G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(p, v)
-        m_scr[...] = m_new
+        for h in range(n_kv):
+            q = q_ref[0, h].astype(jnp.float32)        # (G, D)
+            k = k_ref[0, :, h].astype(jnp.float32)     # (BS, D)
+            v = v_ref[0, :, h].astype(jnp.float32)     # (BS, D)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+            pos = j * block_size + jax.lax.broadcasted_iota(jnp.int32,
+                                                            s.shape, 1)
+            s = jnp.where(pos < ctx, s, NEG_INF)       # (G, BS)
+            m_prev = m_scr[h]                          # (G, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot(p, v)
+            m_scr[h] = m_new
 
     @pl.when(j == nj - 1)
     def _finalize():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -80,21 +86,21 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     qg = q.reshape(b, kv, g, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kv, maxb),
+        grid=(b, maxb),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d),
-                         lambda b_, h_, j, tables, lens: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda b_, h_, j, tables, lens: (tables[b_, j], 0, h_, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda b_, h_, j, tables, lens: (tables[b_, j], 0, h_, 0)),
+            pl.BlockSpec((1, kv, g, d),
+                         lambda b_, j, tables, lens: (b_, 0, 0, 0)),
+            pl.BlockSpec((1, bs, kv, d),
+                         lambda b_, j, tables, lens: (tables[b_, j], 0, 0, 0)),
+            pl.BlockSpec((1, bs, kv, d),
+                         lambda b_, j, tables, lens: (tables[b_, j], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda b_, h_, j, tables, lens: (b_, h_, 0, 0)),
+        out_specs=pl.BlockSpec((1, kv, g, d),
+                               lambda b_, j, tables, lens: (b_, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),   # running max m
-            pltpu.VMEM((g, 1), jnp.float32),   # running denom l
-            pltpu.VMEM((g, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((kv, g, 1), jnp.float32),   # running max m
+            pltpu.VMEM((kv, g, 1), jnp.float32),   # running denom l
+            pltpu.VMEM((kv, g, d), jnp.float32),   # output accumulator
         ],
     )
     out = pl.pallas_call(

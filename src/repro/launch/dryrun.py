@@ -106,7 +106,7 @@ def _build(cfg: ModelConfig, shape: ShapeConfig, mesh):
 
 def _compile_cell(cfg: ModelConfig, shape: ShapeConfig, mesh):
     fn, args, shardings, donate = _build(cfg, shape, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=shardings,
                           donate_argnums=donate).lower(*args)
         compiled = lowered.compile()

@@ -14,6 +14,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving.batching import GenRequest
 from repro.serving.engine import ContinuousEngine, ServingEngine
@@ -32,6 +33,7 @@ def main():
                     help="run-to-completion baseline instead of continuous batching")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=True)
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     max_seq = args.prompt_len + args.new_tokens + 8
@@ -63,7 +65,8 @@ def main():
     mode = "sequential" if args.sequential else \
         f"continuous x{args.batch_slots} slots (occupancy {occ:.0%})"
     print(f"served {len(done)} requests, {n_tok} tokens in {dt:.1f}s "
-          f"({n_tok/dt:.1f} tok/s on CPU, reduced config, {mode})")
+          f"({n_tok/dt:.1f} tok/s on {jax.devices()[0].platform}, reduced "
+          f"config, {mode})")
 
 
 def _drain_and_exit(engine: ContinuousEngine):

@@ -97,12 +97,8 @@ def _shard(cfg: ModelConfig, x, *axes):
 
 def _expert_parallel(cfg: ModelConfig) -> bool:
     """True when experts shard over the ambient mesh's "model" axis."""
-    from jax._src import mesh as mesh_lib
-    pm = mesh_lib.thread_resources.env.physical_mesh
-    if pm.empty:
-        return False
-    sizes = dict(zip(pm.axis_names, pm.devices.shape))
-    m = sizes.get("model", 1)
+    from repro.distributed.sharding import ambient_mesh_sizes
+    m = (ambient_mesh_sizes() or {}).get("model", 1)
     return m > 1 and cfg.n_experts % m == 0
 
 

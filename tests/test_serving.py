@@ -296,13 +296,17 @@ def test_continuous_eos_frees_slot_early(qwen_setup):
     prompt = np.random.default_rng(3).integers(0, cfg.vocab_size, size=8).tolist()
     probe.add(GenRequest(id=0, prompt=prompt, max_new=8))
     full = probe.run()[0].generated
-    eos = full[3]   # stop on the 4th emitted token
-    eng = ContinuousEngine(cfg, params, n_slots=1, max_seq=48, eos_id=eos)
+    # stop on the first token that does not occur earlier in the stream, so
+    # the run must end exactly at its first occurrence (index >= 1)
+    stop = next(i for i in range(1, len(full)) if full[i] not in full[:i])
+    eng = ContinuousEngine(cfg, params, n_slots=1, max_seq=48,
+                           eos_id=full[stop])
     eng.add(GenRequest(id=0, prompt=prompt, max_new=8))
     eng.add(GenRequest(id=1, prompt=prompt, max_new=8))  # waits for the slot
     done = eng.run()
     first = next(r for r in done if r.id == 0)
-    assert first.generated == full[:4]        # stopped AT the eos token
+    assert first.generated == full[:stop + 1]  # stopped AT the eos token
+    assert len(first.generated) < 8            # ...before max_new
     assert len(done) == 2                     # the freed slot served req 1
 
 
