@@ -16,6 +16,10 @@ class GenRequest:
     generated: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
     eos_id: Optional[int] = None   # per-request stop token (early slot free)
+    # host clock (time.perf_counter) when the engine last queued the request
+    # and when its last admission attempt started
+    queued_at: Optional[float] = None
+    admitted_at: Optional[float] = None
 
     @property
     def remaining(self) -> int:

@@ -26,6 +26,7 @@ from typing import Dict, Hashable, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models import model as model_mod
@@ -46,6 +47,16 @@ def _pick(logits, vocab_size: int, temperature: float, rng):
 
 _CACHE_BUCKET = 64  # sequential-path caches sized in buckets, not max_seq
 
+# Host spans (``jax.profiler.TraceAnnotation``) the continuous engines open
+# around their work, on the profiler's clock: an admission (stats ``req``,
+# ``slot``, ``ctx``) holding its context's prefill (``req``, ``ctx``), the
+# dense graft (``req``, ``slot``) and the pick of its first token (``req``);
+# a decode wave's dispatch, pick with its blocking read-back, and emit plus
+# release (each ``active``: slots in the wave). Free when no profiler runs.
+SPANS = ADMIT, PREFILL, GRAFT, PICK, DECODE, EMIT = (
+    "engine.admit", "engine.prefill", "engine.graft", "engine.pick",
+    "engine.decode", "engine.emit")
+
 
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, params, max_seq: int = 512):
@@ -55,7 +66,6 @@ class ServingEngine:
         self.cfg = cfg
         self.params = params
         self.max_seq = max_seq
-        self.peak_cache_bytes = 0
         self._prefill = jax.jit(functools.partial(model_mod.prefill, cfg=cfg))
         self._decode = jax.jit(functools.partial(model_mod.decode_step, cfg=cfg))
 
@@ -88,9 +98,6 @@ class ServingEngine:
         seq_cap = min(self.max_seq, -(-(s + n_new) // _CACHE_BUCKET) * _CACHE_BUCKET)
         logits, cache = self._prefill(self.params, {"tokens": jnp.asarray(tokens)})
         cache = self._grown_cache(cache, b, seq_cap)
-        self.peak_cache_bytes = max(
-            self.peak_cache_bytes,
-            sum(leaf.nbytes for leaf in jax.tree.leaves(cache)))
         rng = jax.random.PRNGKey(seed)
         # key hygiene: the root key is only ever split, never consumed — the
         # first sample uses a subkey so tokens 0 and 1 are uncorrelated
@@ -192,36 +199,41 @@ class ContinuousEngine:
     def add(self, req: GenRequest):
         """Admit a request: queue it and prefill any slot it (or a cascade of
         early-EOS admissions) frees up. Safe to call mid-decode."""
+        req.queued_at = time.perf_counter()
         for slot in self.batcher.add(req):
             self._admit(slot)
 
     def _admit(self, slot: int):
         req = self.batcher.slots[slot]
         while req is not None:
-            if req.remaining == 0:   # resumed partial that was already full
-                req.done = True
-                self.batcher.finished.append(req)
-                self.batcher.slots[slot] = None
-                self._reap()
-            else:
-                context = list(req.prompt) + list(req.generated)
-                assert len(context) + req.remaining <= self.max_seq, \
-                    (len(context), req.remaining, self.max_seq)
-                logits = self._context_into_slot(slot, req, context)
-                if logits is None:
-                    # mid-decode state restored (paged parked resume): the
-                    # next token comes from step(), not an admission prefill
-                    return
-                tok = int(np.asarray(self._pick_row(logits))[0, 0])
-                req.generated.append(tok)
-                self.n_emitted += 1
-                self.positions[slot] = len(context)
-                self.last_tok[slot, 0] = tok
-                finished = self.batcher._finish_if_done(slot, req, tok,
-                                                        self.eos_id)
-                self._reap()
-                if not finished:
-                    return
+            req.admitted_at = time.perf_counter()
+            context = list(req.prompt) + list(req.generated)
+            with TraceAnnotation(ADMIT, req=req.id, slot=slot,
+                                 ctx=len(context)):
+                if req.remaining == 0:   # resumed partial that was already full
+                    req.done = True
+                    self.batcher.finished.append(req)
+                    self.batcher.slots[slot] = None
+                    self._reap()
+                else:
+                    assert len(context) + req.remaining <= self.max_seq, \
+                        (len(context), req.remaining, self.max_seq)
+                    logits = self._context_into_slot(slot, req, context)
+                    if logits is None:
+                        # mid-decode state restored (paged parked resume): the
+                        # next token comes from step(), not an admission prefill
+                        return
+                    with TraceAnnotation(PICK, req=req.id):
+                        tok = int(np.asarray(self._pick_row(logits))[0, 0])
+                    req.generated.append(tok)
+                    self.n_emitted += 1
+                    self.positions[slot] = len(context)
+                    self.last_tok[slot, 0] = tok
+                    finished = self.batcher._finish_if_done(slot, req, tok,
+                                                            self.eos_id)
+                    self._reap()
+                    if not finished:
+                        return
             self.batcher._fill()
             req = self.batcher.slots[slot]
 
@@ -230,9 +242,11 @@ class ContinuousEngine:
         """Install ``context``'s KV into ``slot``; returns the last-position
         logits (B=1), or None when the slot was restored to a mid-decode
         state and no admission token should be emitted (paged resume)."""
-        logits, pre = self._prefill(
-            self.params, {"tokens": jnp.asarray([context], jnp.int32)})
-        self._slot_state.graft(pre, slot)
+        with TraceAnnotation(PREFILL, req=req.id, ctx=len(context)):
+            logits, pre = self._prefill(
+                self.params, {"tokens": jnp.asarray([context], jnp.int32)})
+        with TraceAnnotation(GRAFT, req=req.id, slot=slot):
+            self._slot_state.graft(pre, slot)
         self.prefill_tokens += len(context)
         return logits
 
@@ -255,13 +269,16 @@ class ContinuousEngine:
         """One batched decode: every active slot advances one token; finished
         slots are refilled (and prefilled) without stopping the loop. Returns
         the number of tokens emitted."""
-        if not self.batcher.active():
+        active = self.batcher.active()
+        if not active:
             return 0
         pos = np.minimum(self.positions, self.max_seq - 1)
-        logits = self._decode_active(pos)
+        with TraceAnnotation(DECODE, active=len(active)):
+            logits = self._decode_active(pos)
         # re-read: _decode_active may have preempted a slot to reclaim memory
         active = self.batcher.active()
-        toks = np.asarray(self._pick_row(logits))  # (n_slots,1)
+        with TraceAnnotation(PICK, active=len(active)):
+            toks = np.asarray(self._pick_row(logits))  # (n_slots,1)
         self.n_decode_steps += 1
         self.n_slot_steps += len(active)
         slot_of = {req.id: i for i, req in active.items()}
@@ -272,10 +289,11 @@ class ContinuousEngine:
             self.last_tok[i, 0] = toks[i, 0]
             return int(toks[i, 0])
 
-        filled = self.batcher.step(emit, eos_id=self.eos_id)
+        with TraceAnnotation(EMIT, active=len(active)):
+            filled = self.batcher.step(emit, eos_id=self.eos_id)
+            self._reap()
         emitted = len(active)
         self.n_emitted += len(active)
-        self._reap()
         for slot in filled:
             self._admit(slot)
         return emitted
@@ -509,12 +527,14 @@ class PagedContinuousEngine(ContinuousEngine):
             try:
                 if best_seq is not None:
                     self.kv.fork(best_seq, key, best_n)
-                    logits = self._extend(key, context, best_n)
+                    with TraceAnnotation(PREFILL, req=req.id, ctx=len(context)):
+                        logits = self._extend(key, context, best_n)
                     self.share_hits += 1       # only successful installs count
                     self.shared_tokens += best_n
                 else:
                     self.kv.create(key)
-                    logits = self._install_prefill(key, context)
+                    with TraceAnnotation(PREFILL, req=req.id, ctx=len(context)):
+                        logits = self._install_prefill(key, context)
                 self._slot_seq[slot] = key
                 return logits
             except OutOfBlocks:
