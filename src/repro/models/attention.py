@@ -246,12 +246,37 @@ def init_kv_cache_shape(cfg: ModelConfig, batch: int, seq_len: int):
     return (batch, s_cache, cfg.n_kv_heads, cfg.head_dim)
 
 
-def gqa_decode(p, x, k_cache, v_cache, pos, cfg: ModelConfig):
-    """One-token decode. x: (B,1,D); caches: (B,Sc,KV,Dh); pos: scalar int32
-    current position, or an (B,) int32 vector giving each batch row its own
-    position (continuous batching: every slot decodes at its own offset,
-    masked independently). Returns (out, new_k_cache, new_v_cache). For SWA
-    the cache is a ring buffer of width ``sliding_window``.
+def _write_entry(cache, entry, layer, slot):
+    """Write the new token's ``entry`` (B, ...) into ``layer`` of a
+    layer-stacked cache (L,B,Sc,...) at sequence slot ``slot``: a scalar,
+    or (B,) with one slot per row, where a row whose slot is out of range is
+    dropped by the scatter rather than corrupting a neighbour (callers clamp
+    idle rows)."""
+    entry = entry.astype(cache.dtype)
+    if jnp.ndim(slot):
+        rows = jnp.arange(entry.shape[0])
+        return cache.at[layer, rows, slot].set(entry, mode="drop")
+    entry = entry.reshape((1, entry.shape[0], 1) + entry.shape[1:])
+    return jax.lax.dynamic_update_slice(
+        cache, entry, (layer, 0, slot) + (0,) * (entry.ndim - 3))
+
+
+def _layer_of(cache, layer):
+    return jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+
+
+def gqa_decode(p, x, k_cache, v_cache, pos, layer, cfg: ModelConfig):
+    """One-token decode of layer ``layer`` (int32 scalar). x: (B,1,D);
+    caches: the whole layer stack (L,B,Sc,KV,Dh) that a decode scan carries;
+    pos: scalar int32 current position, or an (B,) int32 vector giving each
+    batch row its own position (continuous batching: every slot decodes at
+    its own offset, masked independently). Returns (out, new_k_cache,
+    new_v_cache). For SWA the cache is a ring buffer of width
+    ``sliding_window``.
+
+    The new row is written into the stack first and the layer's slice read
+    back after, so a scan that carries donated caches updates them in place
+    rather than copying the layer out.
     """
     b = x.shape[0]
     dt = x.dtype
@@ -261,20 +286,13 @@ def gqa_decode(p, x, k_cache, v_cache, pos, cfg: ModelConfig):
     q, k, v = _project_qkv(p, x, cfg)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-    s_cache = k_cache.shape[1]
+    s_cache = k_cache.shape[2]
     slot = pos % s_cache if cfg.sliding_window else pos
-    if per_row:
-        # each row writes its own cache line; OOB rows (clamped by callers)
-        # are dropped by the scatter rather than corrupting a neighbour
-        k_cache = k_cache.at[jnp.arange(b), slot].set(
-            k[:, 0].astype(k_cache.dtype), mode="drop")
-        v_cache = v_cache.at[jnp.arange(b), slot].set(
-            v[:, 0].astype(v_cache.dtype), mode="drop")
-    else:
-        k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k.astype(k_cache.dtype), slot, 1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v.astype(v_cache.dtype), slot, 1)
-    k_cache = _shard(cfg, k_cache, BATCH_AXES, "model", None, None)
-    v_cache = _shard(cfg, v_cache, BATCH_AXES, "model", None, None)
+    k_cache = _write_entry(k_cache, k[:, 0], layer, slot)
+    v_cache = _write_entry(v_cache, v[:, 0], layer, slot)
+    k_cache = _shard(cfg, k_cache, None, BATCH_AXES, "model", None, None)
+    v_cache = _shard(cfg, v_cache, None, BATCH_AXES, "model", None, None)
+    kc, vc = _layer_of(k_cache, layer), _layer_of(v_cache, layer)
     # positions held in each cache slot, per batch row when pos is a vector
     idx = jnp.arange(s_cache)
     row_pos = pos[:, None] if per_row else pos  # (B,1) | scalar
@@ -293,11 +311,11 @@ def gqa_decode(p, x, k_cache, v_cache, pos, cfg: ModelConfig):
     # flash-decode layout: scores (B,KV,G,1,S) with the cache SEQ dim sharded
     # over "model"; softmax stats and the output are combined by tiny
     # all-reduces instead of gathering the cache.
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qh, k_cache.astype(dt)).astype(jnp.float32) * scale
+    scores = jnp.einsum("bqkgd,bskd->bkgqs", qh, kc.astype(dt)).astype(jnp.float32) * scale
     scores = _shard(cfg, scores, BATCH_AXES, None, None, None, "model")
     scores = jnp.where(valid[:, None, None, None, :], scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1).astype(dt)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", w, v_cache.astype(dt))
+    out = jnp.einsum("bkgqs,bskd->bqkgd", w, vc.astype(dt))
     out = out.reshape(b, 1, cfg.n_heads * cfg.head_dim)
     out = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(dt))
     return out, k_cache, v_cache
@@ -369,13 +387,16 @@ def mla_attention(p, x, positions, cfg: ModelConfig):
     return jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(dt))
 
 
-def mla_decode(p, x, c_cache, pos, cfg: ModelConfig):
-    """Absorbed-matrix MLA decode over a compressed cache. ``pos`` is a
-    scalar int32 or an (B,) per-row position vector (continuous batching).
+def mla_decode(p, x, c_cache, pos, layer, cfg: ModelConfig):
+    """Absorbed-matrix MLA decode of layer ``layer`` over a compressed cache.
+    ``pos`` is a scalar int32 or an (B,) per-row position vector (continuous
+    batching).
 
-    Cache layout: (B, S, kv_lora_rank + qk_rope_dim) — c_kv ++ rope'd k_rope.
-    The up-projections are absorbed into the query/output paths so decode cost
-    is O(S * (r + rope)) per head, which is the MLA deployment trick.
+    Cache layout: the layer stack (L, B, S, kv_lora_rank + qk_rope_dim) —
+    c_kv ++ rope'd k_rope — written and read at ``layer`` and returned
+    whole, as in :func:`gqa_decode`. The up-projections are absorbed into
+    the query/output paths so decode cost is O(S * (r + rope)) per head,
+    which is the MLA deployment trick.
     """
     b = x.shape[0]
     dt = x.dtype
@@ -388,14 +409,11 @@ def mla_decode(p, x, c_cache, pos, cfg: ModelConfig):
     k_rope = jnp.einsum("bsd,dr->bsr", x, p["w_krope"].astype(dt))
     k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
     entry = jnp.concatenate([c_kv, k_rope], axis=-1)
-    if per_row:
-        c_cache = c_cache.at[jnp.arange(b), pos].set(
-            entry[:, 0].astype(c_cache.dtype), mode="drop")
-    else:
-        c_cache = jax.lax.dynamic_update_slice_in_dim(c_cache, entry.astype(c_cache.dtype), pos, 1)
-    c_cache = _shard(cfg, c_cache, BATCH_AXES, "model", None)
-    cache_c = c_cache[..., :r].astype(dt)      # (B,S,r)
-    cache_rope = c_cache[..., r:].astype(dt)   # (B,S,rope)
+    c_cache = _write_entry(c_cache, entry[:, 0], layer, pos)
+    c_cache = _shard(cfg, c_cache, None, BATCH_AXES, "model", None)
+    cc = _layer_of(c_cache, layer)
+    cache_c = cc[..., :r].astype(dt)      # (B,S,r)
+    cache_rope = cc[..., r:].astype(dt)   # (B,S,rope)
     # absorb W_uk into q: (B,1,H,nope) @ (r, H*nope) -> (B,1,H,r)
     w_uk = p["w_uk"].astype(dt).reshape(r, cfg.n_heads, cfg.qk_nope_dim)
     q_abs = jnp.einsum("bqhd,rhd->bqhr", q_nope, w_uk)
@@ -403,8 +421,8 @@ def mla_decode(p, x, c_cache, pos, cfg: ModelConfig):
     scores = (jnp.einsum("bqhr,bsr->bhqs", q_abs, cache_c)
               + jnp.einsum("bqhd,bsd->bhqs", q_rope, cache_rope)).astype(jnp.float32) * scale
     scores = _shard(cfg, scores, BATCH_AXES, None, None, "model")
-    valid = jnp.arange(c_cache.shape[1]) <= (pos[:, None] if per_row else pos)
-    valid = jnp.broadcast_to(valid, (b, c_cache.shape[1]))
+    valid = jnp.arange(cc.shape[1]) <= (pos[:, None] if per_row else pos)
+    valid = jnp.broadcast_to(valid, (b, cc.shape[1]))
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1).astype(dt)
     ctx = jnp.einsum("bhqs,bsr->bqhr", w, cache_c)  # (B,1,H,r)

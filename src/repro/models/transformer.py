@@ -258,62 +258,83 @@ def _ffn_decode(p, x, cfg: ModelConfig):
     return x + h
 
 
-def _ssm_decode_body(p, x, state, conv, cfg: ModelConfig):
-    h, state, conv = ssm_mod.mamba_decode(p["mixer"], _norm(x, p["ln"], cfg), state, conv, cfg)
-    return x + h, state, conv
+def _entry(a, at):
+    """The entry of stacked ``a`` at the leading indices ``at``."""
+    n = len(at)
+    start = tuple(at) + (0,) * (a.ndim - n)
+    return jax.lax.dynamic_slice(a, start, (1,) * n + a.shape[n:]).reshape(a.shape[n:])
+
+
+def _put_entry(a, at, v):
+    """``a`` with its entry at the leading indices ``at`` replaced by ``v``."""
+    v = v.astype(a.dtype).reshape((1,) * len(at) + v.shape)
+    return jax.lax.dynamic_update_slice(a, v, tuple(at) + (0,) * (a.ndim - len(at)))
+
+
+def _ssm_decode_body(p, x, state, conv, at, cfg: ModelConfig):
+    """One mamba layer's decode against the stacked ``state``/``conv``: reads
+    the layer's entries at ``at``, then overwrites them with the new ones."""
+    h, st, cv = ssm_mod.mamba_decode(p["mixer"], _norm(x, p["ln"], cfg),
+                                     _entry(state, at), _entry(conv, at), cfg)
+    return x + h, _put_entry(state, at, st), _put_entry(conv, at, cv)
+
+
+def _scan_layers(cfg: ModelConfig, body, x, params, cache):
+    """Run ``body(h, cache, lp, i) -> (h, cache)`` over the layers stacked in
+    ``params``, with the segment's stacked ``cache`` carried whole: layer
+    ``i`` updates its entries of the carried arrays and reads them back.
+    Nothing copies a layer out of the scan's ``xs`` or into a fresh stacked
+    ``ys``, so a donated cache is updated in place."""
+    def step(carry, xs):
+        return body(*carry, *xs), None
+    idx = jnp.arange(_seg_len(params), dtype=jnp.int32)
+    (x, cache), _ = _scan(cfg, step, (x, cache), (params, idx))
+    return x, cache
 
 
 def stack_decode(params, x, cache, pos, cfg: ModelConfig):
     """One-token decode. x: (B,1,D); pos: scalar int32 OR (B,) int32 vector
     (per-slot positions for continuous batching — each batch row attends at
-    its own offset). -> (hidden, new_cache)."""
+    its own offset). -> (hidden, new_cache). Each segment's cache rides in
+    its layer scan's carry (:func:`_scan_layers`), so the caller can donate
+    ``cache`` and have it updated in place."""
     new_cache: Dict[str, Any] = {}
     for seg in segments_for(cfg):
         p = params[seg.name]
         c = cache[seg.name]
         if seg.kind in ("dense", "moe"):
             if cfg.use_mla:
-                def body(h, xs):
-                    lp, cc = xs
-                    a, cc = attn_mod.mla_decode(lp["attn"], _norm(h, lp["ln1"], cfg), cc, pos, cfg)
-                    h = _ffn_decode(lp, h + a, cfg)
-                    return h, cc
-                x, ccs = _scan(cfg, body, x, (p, c["c"]))
-                new_cache[seg.name] = {"c": ccs}
+                def body(h, cc, lp, i):
+                    a, c_new = attn_mod.mla_decode(lp["attn"], _norm(h, lp["ln1"], cfg),
+                                                   cc["c"], pos, i, cfg)
+                    return _ffn_decode(lp, h + a, cfg), {"c": c_new}
             else:
-                def body(h, xs):
-                    lp, kc, vc = xs
-                    a, kc, vc = attn_mod.gqa_decode(lp["attn"], _norm(h, lp["ln1"], cfg), kc, vc, pos, cfg)
-                    h = _ffn_decode(lp, h + a, cfg)
-                    return h, (kc, vc)
-                x, (kcs, vcs) = _scan(cfg, body, x, (p, c["k"], c["v"]))
-                new_cache[seg.name] = {"k": kcs, "v": vcs}
+                def body(h, cc, lp, i):
+                    a, kc, vc = attn_mod.gqa_decode(lp["attn"], _norm(h, lp["ln1"], cfg),
+                                                    cc["k"], cc["v"], pos, i, cfg)
+                    return _ffn_decode(lp, h + a, cfg), {"k": kc, "v": vc}
+            x, new_cache[seg.name] = _scan_layers(cfg, body, x, p, c)
         elif seg.kind == "ssm":
-            def body_s(h, xs):
-                lp, st, cv = xs
-                h, st, cv = _ssm_decode_body(lp, h, st, cv, cfg)
-                return h, (st, cv)
-            x, (sts, cvs) = _scan(cfg, body_s, x, (p, c["state"], c["conv"]))
-            new_cache[seg.name] = {"state": sts, "conv": cvs}
+            def body_s(h, cc, lp, i):
+                h, st, cv = _ssm_decode_body(lp, h, cc["state"], cc["conv"], (i,), cfg)
+                return h, {"state": st, "conv": cv}
+            x, new_cache[seg.name] = _scan_layers(cfg, body_s, x, p, c)
         elif seg.kind == "hybrid_group":
             shared = p["shared"]
 
-            def body_g(h, xs):
-                gp, st, cv, kc, vc = xs
-
-                def inner(hh, ys):
-                    lp, s1, c1 = ys
-                    hh, s1, c1 = _ssm_decode_body(lp, hh, s1, c1, cfg)
-                    return hh, (s1, c1)
-                h, (st, cv) = _scan(cfg, inner, h, (gp, st, cv))
+            def body_g(h, cc, gp, g):
+                def inner(hh, sc, lp, j):
+                    hh, st, cv = _ssm_decode_body(lp, hh, sc["state"], sc["conv"], (g, j), cfg)
+                    return hh, {"state": st, "conv": cv}
+                h, sc = _scan_layers(cfg, inner, h, gp,
+                                     {"state": cc["state"], "conv": cc["conv"]})
                 xin = _norm(h, shared["ln1"], cfg)
-                a, kc, vc = attn_mod.gqa_decode(shared["attn"], xin, kc, vc, pos, cfg)
+                a, kc, vc = attn_mod.gqa_decode(shared["attn"], xin, cc["k"], cc["v"],
+                                                pos, g, cfg)
                 h = h + a
                 h = h + apply_mlp(shared["mlp"], _norm(h, shared["ln2"], cfg), cfg)
-                return h, (st, cv, kc, vc)
-            x, (sts, cvs, kcs, vcs) = _scan(
-                cfg, body_g, x, (p["mamba"], c["state"], c["conv"], c["k"], c["v"]))
-            new_cache[seg.name] = {"state": sts, "conv": cvs, "k": kcs, "v": vcs}
+                return h, {**sc, "k": kc, "v": vc}
+            x, new_cache[seg.name] = _scan_layers(cfg, body_g, x, p["mamba"], c)
         else:
             raise ValueError(seg.kind)
     return x, new_cache

@@ -157,7 +157,9 @@ class ContinuousEngine:
         self.last_tok = np.zeros((n_slots, 1), np.int32)
         self._rng = jax.random.PRNGKey(seed)
         self._prefill = jax.jit(functools.partial(model_mod.prefill, cfg=cfg))
-        self._decode = jax.jit(functools.partial(model_mod.decode_step, cfg=cfg))
+        # the slot state is donated: each wave updates it in place
+        self._decode = jax.jit(functools.partial(model_mod.decode_step, cfg=cfg),
+                               donate_argnums=(2,))
         # counters for occupancy/throughput accounting
         self.n_decode_steps = 0
         self.n_emitted = 0       # tokens produced (prefill-picked + decoded)
@@ -173,7 +175,10 @@ class ContinuousEngine:
     @property
     def cache(self):
         """The live decode-state pytree. Settable: the elastic-serving
-        migration protocol transplants it wholesale across meshes."""
+        migration protocol transplants it wholesale across meshes. Decode
+        and graft take it donated and replace it, so a tree read here is
+        dead (its leaves deleted) after the next ``step()`` or ``add()``
+        that admits: read it again rather than holding it."""
         if self._slot_state is None:
             raise AttributeError(
                 "paged engine keeps decode state in the block pool (.kv), "

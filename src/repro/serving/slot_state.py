@@ -16,7 +16,12 @@ architecture it is serving:
   non-batch axis first, so no stale state from the row's previous occupant
   survives;
 * drain/migration can read or replace the whole tree (``engine.cache`` stays
-  an assignable attribute for the elastic-serving migration protocol).
+  an assignable attribute for the elastic-serving migration protocol);
+* both programs that rewrite the tree take it donated and update it in
+  place: the engine's batched ``decode_step`` (which carries each segment's
+  stacked cache through its layer scan) and the graft here. A tree read
+  from ``engine.cache`` is therefore dead after the next ``step()`` or
+  admitting ``add()``; read the attribute again instead of holding it.
 
 Anything the model exposes through ``cache_spec``/``init_cache`` therefore
 serves through the same engine, paged or dense, with zero engine changes.
@@ -74,7 +79,8 @@ class SlotBatchState:
         self.batch_axes = find_batch_axes(cfg, max_seq)
         self._graft = jax.jit(
             lambda live, pre, slot: graft_slot(live, pre, slot,
-                                               self.batch_axes))
+                                               self.batch_axes),
+            donate_argnums=(0,))
 
     def graft(self, pre: Any, slot: int) -> None:
         """Install a batch-1 prefill state into ``slot``'s batch row."""

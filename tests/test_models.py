@@ -130,9 +130,10 @@ def test_mla_decode_absorption():
     x = jax.random.normal(jax.random.PRNGKey(2), (bsz, s, cfg.d_model), jnp.float32)
     positions = jnp.broadcast_to(jnp.arange(s), (bsz, s))
     out_full = A.mla_attention(p, x, positions, cfg)
-    cache = jnp.zeros((bsz, s, cfg.kv_lora_rank + cfg.qk_rope_dim), jnp.float32)
+    cache = jnp.zeros((1, bsz, s, cfg.kv_lora_rank + cfg.qk_rope_dim), jnp.float32)
     for t in range(s):
-        out_t, cache = A.mla_decode(p, x[:, t:t + 1], cache, jnp.int32(t), cfg)
+        out_t, cache = A.mla_decode(p, x[:, t:t + 1], cache, jnp.int32(t),
+                                    jnp.int32(0), cfg)
         np.testing.assert_allclose(out_t, out_full[:, t:t + 1], atol=1e-5, rtol=1e-4)
 
 
@@ -146,10 +147,11 @@ def test_swa_ring_cache_wraps():
     x = jax.random.normal(jax.random.PRNGKey(6), (bsz, s, cfg.d_model), jnp.float32)
     positions = jnp.broadcast_to(jnp.arange(s), (bsz, s))
     out_full = A.gqa_attention(p, x, positions, cfg)
-    kc = jnp.zeros((bsz, w, cfg.n_kv_heads, cfg.head_dim), jnp.float32)
+    kc = jnp.zeros((1, bsz, w, cfg.n_kv_heads, cfg.head_dim), jnp.float32)
     vc = jnp.zeros_like(kc)
     for t in range(s):
-        out_t, kc, vc = A.gqa_decode(p, x[:, t:t + 1], kc, vc, jnp.int32(t), cfg)
+        out_t, kc, vc = A.gqa_decode(p, x[:, t:t + 1], kc, vc, jnp.int32(t),
+                                     jnp.int32(0), cfg)
         np.testing.assert_allclose(out_t, out_full[:, t:t + 1], atol=1e-5, rtol=1e-4)
 
 

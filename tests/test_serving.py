@@ -229,6 +229,42 @@ def test_kernel_impls_token_identity_per_arch(arch):
 
 
 @jaxtier
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "internlm2-1.8b",
+                                  "mixtral-8x22b", "deepseek-v2-lite-16b",
+                                  "mamba2-2.7b", "zamba2-2.7b"])
+def test_decode_and_graft_update_slot_state_in_place(arch):
+    """Both programs that rewrite the slot state take it donated, for every
+    cache family (GQA, SWA ring + MoE, MLA, SSM state + conv, hybrid): a
+    tree read from ``engine.cache`` is deleted by the next admission's graft
+    and by the next decode wave, with no buffer refused for donation."""
+    import warnings
+
+    import jax
+    import numpy as np
+    from repro.configs import get_config
+    from repro.models import init_params
+    from repro.serving.engine import ContinuousEngine
+    cfg = get_config(arch, smoke=True)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    eng = ContinuousEngine(cfg, params, n_slots=2, max_seq=32)
+    prompt = np.random.default_rng(7).integers(0, cfg.vocab_size, 6).tolist()
+    with warnings.catch_warnings():
+        warnings.filterwarnings("error", message=".*donated buffers")
+        for i, rewrite in enumerate((
+                lambda: eng.add(GenRequest(id=0, prompt=prompt, max_new=4)),
+                eng.step,
+                lambda: eng.add(GenRequest(id=1, prompt=prompt, max_new=4)),
+                eng.step)):
+            held = jax.tree.leaves(eng.cache)
+            rewrite()
+            assert all(leaf.is_deleted() for leaf in held), i
+            assert not any(leaf.is_deleted()
+                           for leaf in jax.tree.leaves(eng.cache)), i
+    got = {r.id: r.generated for r in eng.run()}
+    assert got[0] == got[1]   # the same prompt, admitted a wave apart
+
+
+@jaxtier
 @pytest.mark.parametrize("arch", ["deepseek-v2-lite-16b", "mamba2-2.7b",
                                   "zamba2-2.7b"])
 def test_drain_resume_nontransformer_state(arch):

@@ -147,3 +147,24 @@ def test_qwen_full_decode_step_compiles_with_kernels(one_chip, qwen_full,
                          _sds(one_chip, (4, 1), jnp.int32), cache,
                          _sds(one_chip, (4,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_qwen_full_decode_step_updates_cache_in_place(one_chip, qwen_full,
+                                                      compiled_kernels):
+    """At a 64-slot, 768-position wave the donated cache is aliased whole to
+    the output, and the program holds no second copy of any of it, not even
+    one layer's K slice: each layer writes its row into the stacked cache
+    its scan carries, rather than into a fresh stacked result."""
+    from repro.models import model as M
+    cfg, params = qwen_full
+    slots, max_seq = 64, 768
+    spec = M.cache_spec(cfg, slots, max_seq)
+    cache = jax.tree.map(lambda l: _sds(one_chip, l.shape, l.dtype), spec)
+    mem = jax.jit(functools.partial(M.decode_step, cfg=cfg),
+                  donate_argnums=(2,)).lower(
+        params, _sds(one_chip, (slots, 1), jnp.int32), cache,
+        _sds(one_chip, (slots,), jnp.int32)).compile().memory_analysis()
+    cache_bytes = sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(spec))
+    layer_k_bytes = slots * max_seq * cfg.n_kv_heads * cfg.head_dim * 2
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < layer_k_bytes
