@@ -1,6 +1,8 @@
-"""Operations and bytes the algorithms need, computed from shapes, and the
+"""Operations and bytes the kernels need, computed from shapes, and the
 chip's peaks. Nothing here reads the program: a configuration's sizes
 (the ``model`` group of its file) and the call's lengths are the input.
+A whole model step's FLOPs are counted by the configuration's own plain
+reference (``reference/<name>.py``: ``prefill_flops``, ``decode_flops``).
 
 FLOPs count a multiply-add as two. A causal attention or SSD needs only the
 lower triangle, so that is what is counted; work a kernel spends on masked
@@ -72,48 +74,3 @@ def ssd_bytes(m: Dict[str, Any], length: int, act_bytes: int = 2) -> float:
     reads = length * (h * p * act_bytes + h * 4 + 2 * g * n * act_bytes)
     writes = length * h * p * 4 + h * p * n * 4
     return reads + writes
-
-
-# --- model steps -------------------------------------------------------------
-def _dense_matmul_per_token(m):
-    d, f = m["d_model"], m["d_ff"]
-    qd = m["n_heads"] * m["head_dim"]
-    kd = m["n_kv_heads"] * m["head_dim"]
-    return m["n_layers"] * 2 * (d * qd + 2 * d * kd + qd * d + 3 * d * f)
-
-
-def _ssm_per_token(m):
-    d, w = m["d_model"], m["d_conv"]
-    h, p, n = _ssd_heads(m)
-    di = h * p
-    gn = m["ssm_ngroups"] * n
-    cd = di + 2 * gn
-    return m["n_layers"] * 2 * (d * (2 * di + 2 * gn + h) + cd * w + di * d)
-
-
-def _head(m):
-    return 2 * m["d_model"] * m["vocab_size"]
-
-
-def prefill_flops(m: Dict[str, Any], length: int) -> float:
-    """One prompt of ``length`` through the model; logits at the last position."""
-    if m["family"] == "dense":
-        return (length * _dense_matmul_per_token(m)
-                + m["n_layers"] * flash_flops(m, length) + _head(m))
-    if m["family"] == "ssm":
-        return (length * _ssm_per_token(m)
-                + m["n_layers"] * ssd_flops(m, length) + _head(m))
-    raise ValueError(f"no prefill count for family {m['family']!r}")
-
-
-def decode_flops(m: Dict[str, Any], context: int) -> float:
-    """One token of one sequence whose cache holds ``context`` positions,
-    the new one included."""
-    if m["family"] == "dense":
-        attn = m["n_layers"] * 4 * m["n_heads"] * m["head_dim"] * context
-        return _dense_matmul_per_token(m) + attn + _head(m)
-    if m["family"] == "ssm":
-        h, p, n = _ssd_heads(m)
-        # state decay, the dt x B^T update and the C contraction
-        return _ssm_per_token(m) + m["n_layers"] * 5 * h * p * n + _head(m)
-    raise ValueError(f"no decode count for family {m['family']!r}")

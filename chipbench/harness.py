@@ -316,6 +316,12 @@ class Context:
     reduction: Any                        # trace_reduce.Reduction or None
     peaks: Dict[str, Any]
 
+    @property
+    def reference(self):
+        """The configuration's plain reference, which counts its model
+        step's FLOPs (``prefill_flops``, ``decode_flops``)."""
+        return spec.reference(self.cell["config_spec"]["reference"])
+
     def traced_tokens(self):
         """(prompt length, token index) of every token that reached the host
         inside the traced span: index 0 came from an admission prefill,

@@ -1,4 +1,5 @@
-"""How the program's compiled programs and kernels appear in a device trace.
+"""How the program's compiled programs appear in a device trace (a kernel's
+operations are found by the ``PATTERN`` in its roofline metric's file).
 
 The engine jits ``functools.partial(prefill)`` and ``functools.partial(
 decode_step)``, which the trace names ``jit__unknown(<fingerprint>)``: one
@@ -12,11 +13,6 @@ from typing import Dict, List
 
 UNNAMED = "jit__unknown("
 GRAFT = "jit__lambda("
-# kernel name (as a metric names it) -> pattern over trace operation names
-KERNELS = {
-    "flash_attention": r"^%flash_attention(\.\d+)?$",
-    "ssd": r"^%ssd(\.\d+)?$",
-}
 
 
 def classify(module_n: Dict[str, int]) -> Dict[str, List[str]]:

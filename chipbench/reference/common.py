@@ -1,6 +1,6 @@
 """Pieces shared by the plain references: weight generation from a seed,
 float32 matrix products (optionally rounded through fp8 for the control),
-RMSNorm and next-token scoring.
+RMSNorm, next-token scoring and the output head's FLOP count.
 
 Nothing here imports the program. The weight tree is laid out the way the
 program's ``init_params`` lays it out, so one generator feeds both the
@@ -80,6 +80,11 @@ def mm(x, w, quant=None):
 def rms_norm(x, w, eps: float):
     x = x.astype(F32)
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w.astype(F32)
+
+
+def head_flops(m: Dict[str, Any]) -> int:
+    """The output head at one position, a multiply-add counted as two."""
+    return 2 * m["d_model"] * m["vocab_size"]
 
 
 def head_weight(params, m):

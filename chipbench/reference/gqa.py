@@ -6,6 +6,9 @@ SwiGLU MLP, final RMSNorm and a (tied) output head.
 Full sequence, no cache, no batching, no kernels; everything in float32
 at the highest matmul precision. ``quant="fp8"`` rounds every projection's
 weight and input through fp8 (the benchmark's lower-precision control).
+
+``prefill_flops`` and ``decode_flops`` count the FLOPs one model step needs
+(``mfu.prefill`` and ``mfu.decode`` read them).
 """
 from __future__ import annotations
 
@@ -14,7 +17,9 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from reference.common import F32, HIGHEST, fan_in, mm, normal, rms_norm, uniform, vocab_padded
+import counts
+from reference.common import (F32, HIGHEST, fan_in, head_flops, mm, normal, rms_norm, uniform,
+                              vocab_padded)
 
 
 # q and k are drawn at this multiple of the fan-in scale: scores of standard
@@ -93,3 +98,23 @@ def hidden(params, tokens, m: Dict[str, Any], quant=None):
 
     x, _ = jax.lax.scan(layer, x, params["stack"]["dense"])
     return rms_norm(x, params["final_norm"]["w"], eps)
+
+
+def _matmul_per_token(m):
+    d, f = m["d_model"], m["d_ff"]
+    qd = m["n_heads"] * m["head_dim"]
+    kd = m["n_kv_heads"] * m["head_dim"]
+    return m["n_layers"] * 2 * (d * qd + 2 * d * kd + qd * d + 3 * d * f)
+
+
+def prefill_flops(m: Dict[str, Any], length: int) -> float:
+    """One prompt of ``length`` through the model; logits at the last position."""
+    return (length * _matmul_per_token(m)
+            + m["n_layers"] * counts.flash_flops(m, length) + head_flops(m))
+
+
+def decode_flops(m: Dict[str, Any], context: int) -> float:
+    """One token of one sequence whose cache holds ``context`` positions,
+    the new one included."""
+    attn = m["n_layers"] * 4 * m["n_heads"] * m["head_dim"] * context
+    return _matmul_per_token(m) + attn + head_flops(m)

@@ -8,6 +8,9 @@ The recurrence is the definition, not the chunked (SSD) form:
 h_t = exp(dt_t * A) h_{t-1} + dt_t * x_t B_t^T,  y_t = h_t C_t + D x_t.
 Everything in float32 at the highest matmul precision; ``quant="fp8"``
 rounds every projection's weight and input through fp8 (the control).
+
+``prefill_flops`` and ``decode_flops`` count the FLOPs one model step needs
+(``mfu.prefill`` and ``mfu.decode`` read them).
 """
 from __future__ import annotations
 
@@ -16,8 +19,9 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from reference.common import (F32, fan_in, log_uniform, mm, normal, rms_norm, uniform,
-                              vocab_padded)
+import counts
+from reference.common import (F32, fan_in, head_flops, log_uniform, mm, normal, rms_norm,
+                              uniform, vocab_padded)
 
 
 def _sizes(m):
@@ -94,3 +98,24 @@ def hidden(params, tokens, m: Dict[str, Any], quant=None):
 
     x, _ = jax.lax.scan(layer, x, params["stack"]["ssm"])
     return rms_norm(x, params["final_norm"]["w"], eps)
+
+
+def _per_token(m):
+    d, w = m["d_model"], m["d_conv"]
+    di, gn, h, cd = _sizes(m)
+    return m["n_layers"] * 2 * (d * (2 * di + 2 * gn + h) + cd * w + di * d)
+
+
+def prefill_flops(m: Dict[str, Any], length: int) -> float:
+    """One prompt of ``length`` through the model; logits at the last position."""
+    return (length * _per_token(m)
+            + m["n_layers"] * counts.ssd_flops(m, length) + head_flops(m))
+
+
+def decode_flops(m: Dict[str, Any], context: int) -> float:
+    """One token of one sequence; the recurrent state's work does not grow
+    with ``context``."""
+    h = _sizes(m)[2]
+    # state decay, the dt x B^T update and the C contraction
+    return (_per_token(m) + m["n_layers"] * 5 * h * m["ssm_headdim"] * m["ssm_state"]
+            + head_flops(m))

@@ -2,11 +2,17 @@
 
   workloads/<cell>.json     a cell: its configuration, traffic, sizes, rate, check
   configs/<config>.json     a configuration: the sizes it runs and its reference
-  reference/<name>.py       a plain reference (``init_spec``, ``hidden``)
+  reference/<name>.py       a plain reference: ``init_spec`` (weights), ``hidden``
+                            (the check), ``prefill_flops`` and ``decode_flops``
+                            (one model step's FLOPs, read by the mfu metrics)
   traffic/<traffic>.json    a traffic mix, read by ``gen.Traffic``
-  metrics/<metric>.py       a per-layer metric: ``read(ctx) -> float | None``
+  metrics/<metric>.py       a per-layer metric: ``read(ctx) -> float | None``; a
+                            kernel's roofline also holds the ``PATTERN`` that
+                            finds the kernel's operations in a trace
 
-A later change adds a cell, configuration, mix or metric by adding files.
+A later change adds a cell, configuration, mix or metric by adding files. A
+configuration of a family no reference covers brings its reference, all four
+functions in its one new file.
 """
 from __future__ import annotations
 
@@ -38,20 +44,34 @@ def cell(name: str, root: Path = HERE) -> Dict[str, Any]:
     return c
 
 
+REFERENCE_API = ("init_spec", "hidden", "prefill_flops", "decode_flops")
+
+
 def reference(name: str):
-    """The plain reference module ``reference/<name>.py``."""
-    return importlib.import_module(f"reference.{name}")
+    """The plain reference module ``reference/<name>.py``; one that lacks
+    any of ``REFERENCE_API`` raises."""
+    mod = importlib.import_module(f"reference.{name}")
+    missing = [f for f in REFERENCE_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"reference {name!r} lacks {missing}; a reference "
+                             f"gives {list(REFERENCE_API)}")
+    return mod
 
 
-def metric_reader(name: str, root: Path = HERE):
-    """``read`` of ``metrics/<name>.py``; the name may hold dots."""
+def metric_module(name: str, root: Path = HERE):
+    """``metrics/<name>.py``, loaded; the name may hold dots."""
     path = root / "metrics" / f"{name}.py"
     mod_spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}", path)
     if mod_spec is None or not path.exists():
         raise FileNotFoundError(f"no reader for per-layer metric {name!r} at {path}")
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, root: Path = HERE):
+    """``read`` of ``metrics/<name>.py``."""
+    return metric_module(name, root).read
 
 
 def per_layer_for(cell_name: str, bench: Dict[str, Any]) -> List[Dict[str, Any]]:

@@ -4,6 +4,8 @@ a served cell can have, and passes a sound run: a whole run through
 timed path broken underneath."""
 import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -29,9 +31,14 @@ def _run(name, seed, hook=None):
 
 
 def stale_state(engine):
-    """A decode step that returns its state unchanged."""
+    """A decode step that returns its state unchanged: a copy taken before
+    the call, since the call consumes the state it is given (donation)."""
     dec = engine._decode
-    engine._decode = lambda p, tok, cache, pos: (dec(p, tok, cache, pos)[0], cache)
+
+    def decode(p, tok, cache, pos):
+        before = jax.tree.map(jnp.copy, cache)
+        return dec(p, tok, cache, pos)[0], before
+    engine._decode = decode
 
 
 def half_batch(engine):

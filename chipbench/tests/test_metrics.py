@@ -1,6 +1,10 @@
 """Per-layer metric readers on made-up reductions: nothing to read gives
 nothing, and no roofline or mfu share passes 100% for a time at or above
 what the counts allow."""
+import json
+import shutil
+import sys
+
 import pytest
 
 import counts
@@ -8,7 +12,7 @@ import harness
 import programs
 import spec
 import trace_reduce
-from conftest import BENCH
+from conftest import BENCH, DATA
 
 QWEN = spec.cell("qwen2.5-3b.doc-faas")
 MAMBA = spec.cell("mamba2-2.7b.chat-burst")
@@ -67,14 +71,86 @@ def test_roofline_at_the_bound_reads_100(cell, kernel, flops, nbytes):
 @pytest.mark.parametrize("cell", [QWEN, MAMBA])
 def test_mfu_at_peak_reads_100(cell):
     m = cell["config_spec"]["model"]
-    pf = sum(counts.prefill_flops(m, p) for p, j in TOKENS if j == 0)
-    df = sum(counts.decode_flops(m, p + j) for p, j in TOKENS if j >= 1)
+    ref = spec.reference(cell["config_spec"]["reference"])
+    pf = sum(ref.prefill_flops(m, p) for p, j in TOKENS if j == 0)
+    df = sum(ref.decode_flops(m, p + j) for p, j in TOKENS if j >= 1)
     red = _reduction({"jit__unknown(1)": pf / PEAK["bf16_flops"],
                       "jit__unknown(2)": 4 * df / PEAK["bf16_flops"]}, {},
                      module_n={"jit__unknown(1)": 2, "jit__unknown(2)": 40})
     assert _read("mfu.prefill", _ctx(cell, red)) == pytest.approx(100.0)
     assert _read("mfu.decode", _ctx(cell, red)) == pytest.approx(25.0)
     assert _read("mfu.prefill", _ctx(cell, _reduction({}, {}))) is None
+
+
+TOY_STEPS = """
+def init_spec(m):
+    return {}
+
+
+def hidden(params, tokens, m, quant=None):
+    raise NotImplementedError
+
+
+def prefill_flops(m, length):
+    return 1000.0 * length
+"""
+TOY_DECODE = """
+
+def decode_flops(m, context):
+    return 10.0 * context
+"""
+
+
+@pytest.fixture
+def toy_family(tmp_path, monkeypatch):
+    """A configuration of a family no committed configuration has ("moe"),
+    added as new files: its configuration, a cell, and its reference in a
+    directory the ``reference`` package searches. Returns a function that
+    writes the reference and loads the cell."""
+    import reference
+    root = tmp_path / "bench"
+    shutil.copytree(DATA, root)
+    (root / "reference").mkdir()
+    monkeypatch.setattr(reference, "__path__", [*reference.__path__, str(root / "reference")])
+    added = []
+
+    def add(module, source):
+        (root / "reference" / f"{module}.py").write_text(source)
+        name = module.replace("_", "-")
+        (root / "configs" / f"{name}.json").write_text(json.dumps(
+            {"name": name, "reference": module, "kernels": "auto",
+             "model": {"family": "moe", "n_layers": 2, "vocab_size": 128}}))
+        (root / "workloads" / f"{name}.json").write_text(json.dumps(
+            {"config": name, "traffic": "smoke", "why": "a new family", "n_slots": 2,
+             "max_seq": 64, "rate": 2.0, "drain_cap_s": 10,
+             "check": {"requests": 2, "min_tokens": 2, "max_logit_gap": 0.1}}))
+        added.append(f"reference.{module}")
+        return spec.cell(name, root)
+
+    yield add
+    for mod in added:
+        sys.modules.pop(mod, None)
+
+
+def test_mfu_reads_a_new_familys_counts_from_its_reference(toy_family):
+    cell = toy_family("toy_moe", TOY_STEPS + TOY_DECODE)
+    red = _reduction({"jit__unknown(1)": 2e-6, "jit__unknown(2)": 5e-6}, {},
+                     module_n={"jit__unknown(1)": 2, "jit__unknown(2)": 40})
+    peak = PEAK["bf16_flops"]
+    # prompts 1024 and 700; decoded tokens over 1025, 1026 and 705 positions
+    assert _read("mfu.prefill", _ctx(cell, red)) == pytest.approx(
+        100.0 * 1000.0 * (1024 + 700) / (2e-6 * peak))
+    assert _read("mfu.decode", _ctx(cell, red)) == pytest.approx(
+        100.0 * 10.0 * (1025 + 1026 + 705) / (5e-6 * peak))
+
+
+def test_a_reference_without_its_step_counts_is_an_error(toy_family):
+    cell = toy_family("toy_moe_uncounted", TOY_STEPS)
+    red = _reduction({"jit__unknown(1)": 2e-6, "jit__unknown(2)": 5e-6}, {},
+                     module_n={"jit__unknown(1)": 2, "jit__unknown(2)": 40})
+    for name in ("mfu.prefill", "mfu.decode"):
+        with pytest.raises(AttributeError, match="decode_flops"):
+            _read(name, _ctx(cell, red))
 
 
 def test_engine_and_device_shares():

@@ -69,6 +69,16 @@ def test_added_files_are_found_by_name_with_no_edit(tmp_path):
     assert harness.model_config(cell).n_layers == 1
 
 
+def test_every_committed_reference_gives_the_four_functions():
+    assert spec.REFERENCE_API == ("init_spec", "hidden", "prefill_flops", "decode_flops")
+    for c in spec.benchmark()["configs"]:
+        cfg = json.loads((spec.CHECKOUT / c["file"]).read_text())
+        ref = spec.reference(cfg["reference"])
+        assert all(callable(getattr(ref, f, None)) for f in spec.REFERENCE_API)
+        m = cfg["model"]
+        assert ref.prefill_flops(m, 16) > ref.decode_flops(m, 16) > 0
+
+
 def test_missing_metric_reader_raises(tmp_path):
     with pytest.raises(FileNotFoundError):
         spec.metric_reader("no_such_metric", tmp_path)

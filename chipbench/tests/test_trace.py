@@ -6,10 +6,12 @@ import re
 import pytest
 
 import programs
+import spec
 import trace_reduce
 from conftest import DATA
 
 TRACES = {"qwen2.5-3b.doc-faas": "flash_attention", "mamba2-2.7b.chat-burst": "ssd"}
+PATTERNS = {k: spec.metric_module(f"{k}_roofline").PATTERN for k in TRACES.values()}
 
 
 def _profile(cell):
@@ -82,10 +84,10 @@ def test_summed_time_per_kernel(traced):
     cell, _, red = traced
     own = TRACES[cell]
     other = ({"flash_attention", "ssd"} - {own}).pop()
-    assert red.ops_matching(programs.KERNELS[other]) == 0.0
-    rx = re.compile(programs.KERNELS[own])
+    assert red.ops_matching(PATTERNS[other]) == 0.0
+    rx = re.compile(PATTERNS[own])
     by_hand = sum(s for name, s in red.op_s.items() if rx.search(name))
-    assert red.ops_matching(programs.KERNELS[own]) == pytest.approx(by_hand)
+    assert red.ops_matching(PATTERNS[own]) == pytest.approx(by_hand)
     assert red.ops_matching(r"^%rmsnorm") > 0           # rmsnorm runs in every step
     assert not any(name.startswith("%while") for name in red.op_s)
     # every operation is attributed to the program it ran in
