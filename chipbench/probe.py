@@ -13,7 +13,9 @@ of a benchmark run):
   python3 chipbench/probe.py --workload <cell> --mode sweep --rates 4,6,8 --seconds 15 \
       [--arrivals poisson]
 
-Each line printed is one JSON object.
+Each line printed is one JSON object. A cell runs on the chips its entry in
+BENCHMARK.json asks for (one if it has none); a gang cell's lines add its
+events.
 """
 from __future__ import annotations
 
@@ -47,11 +49,11 @@ def main(argv=None) -> int:
     import harness
     import spec
     harness.configure_jax()
-    device = harness.require_chip(1)
-    cell = spec.cell(args.workload)
+    chips = {w["name"]: w["chips"] for w in spec.benchmark()["workloads"]}.get(args.workload, 1)
+    device = harness.require_chip(chips)
+    cell = spec.cell(args.workload, chips=chips, seconds=args.seconds, trace_s=harness.TRACE_S)
     seeds = [int(s) for s in args.seeds.split(",")]
     rates = [float(r) for r in args.rates.split(",")] if args.rates else [cell["rate"]]
-    cs = cell["config_spec"]
     if args.arrivals:
         cell["traffic_spec"] = dict(cell["traffic_spec"], arrivals=args.arrivals)
     for rate in rates if args.mode == "sweep" else [cell["rate"]]:
@@ -67,12 +69,12 @@ def main(argv=None) -> int:
                    "queue": [w.queue_open, w.queue_close], "compiles": w.compiles,
                    "drain_s": w.t_end - w.t_close,
                    "counters": [w.counters_open, w.counters_close]}
+            if w.events:
+                out["events"] = w.events
             if args.mode == "readings":
-                pick = check.sample(served, cell["check"]["requests"], seed)
-                pairs = [served[i] for i in pick]
+                pairs = harness.check_sample(c, seed, served, w)
                 t = time.perf_counter()
-                ref = check.Reference(spec.reference(cs["reference"]), cs["model"],
-                                      harness.make_params(cell, seed))
+                ref = harness.reference(c, seed)
                 out["program_gap"] = check.widest_gap(ref, pairs, cell["max_seq"])
                 t_ref = time.perf_counter() - t
                 out["control_gap"] = check.widest_gap(ref, pairs, cell["max_seq"], control=True)

@@ -8,6 +8,14 @@ graft is a jitted lambda (``jit__lambda(<fingerprint>)``). So, until the
 program names its programs: among the ``jit__unknown`` programs the one run
 most often is the decode step (it runs once per wave; a prompt length
 recurs only on admission) and the rest are prefills.
+
+A gang compiles a decode program for each size it takes, but the reduction
+reads one layout's programs: the span's planes are those of the gang's mesh
+over it, and no event falls inside it. The first engine call after a resize
+runs a variant of its own, taking the slot state uncommitted, which would
+count as a prefill here: ``spec.cell`` refuses an event less than
+``spec.EVENT_MARGIN_S`` before the span, and ``harness.drive`` fails a run
+in which that call has not ended when the span opens.
 """
 from typing import Dict, List
 

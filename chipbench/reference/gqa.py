@@ -69,13 +69,18 @@ def _rope(x, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def hidden(params, tokens, m: Dict[str, Any], quant=None):
-    """tokens (S,) -> final-normed hidden states (S, D)."""
+def embed(params, tokens, m: Dict[str, Any]):
+    """tokens (S,) -> the first layer's input (S, D), in float32."""
+    return params["embed"]["tokens"][tokens].astype(F32)
+
+
+def layers(stack, x, m: Dict[str, Any], quant=None):
+    """The layers of ``stack`` (``params["stack"]`` or a run of its layers,
+    each leaf's leading axis) over x (S, D)."""
     eps = m.get("norm_eps", 1e-5)
     h, kv, dh = m["n_heads"], m["n_kv_heads"], m["head_dim"]
-    s = tokens.shape[0]
+    s = x.shape[0]
     causal = jnp.tril(jnp.ones((s, s), bool))
-    x = params["embed"]["tokens"][tokens].astype(F32)
 
     def layer(x, lp):
         a = lp["attn"]
@@ -96,8 +101,13 @@ def hidden(params, tokens, m: Dict[str, Any], quant=None):
         g = jax.nn.silu(mm(y, p["w_gate"], quant)) * mm(y, p["w_up"], quant)
         return x + mm(g, p["w_down"], quant), None
 
-    x, _ = jax.lax.scan(layer, x, params["stack"]["dense"])
-    return rms_norm(x, params["final_norm"]["w"], eps)
+    x, _ = jax.lax.scan(layer, x, stack["dense"])
+    return x
+
+
+def final(params, x, m: Dict[str, Any]):
+    """The last layer's output -> final-normed hidden states (S, D)."""
+    return rms_norm(x, params["final_norm"]["w"], m.get("norm_eps", 1e-5))
 
 
 def _matmul_per_token(m):

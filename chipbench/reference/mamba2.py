@@ -63,13 +63,18 @@ def init_spec(m: Dict[str, Any]) -> Dict[str, Any]:
     return spec
 
 
-def hidden(params, tokens, m: Dict[str, Any], quant=None):
-    """tokens (S,) -> final-normed hidden states (S, D)."""
+def embed(params, tokens, m: Dict[str, Any]):
+    """tokens (S,) -> the first layer's input (S, D), in float32."""
+    return params["embed"]["tokens"][tokens].astype(F32)
+
+
+def layers(stack, x, m: Dict[str, Any], quant=None):
+    """The layers of ``stack`` (``params["stack"]`` or a run of its layers,
+    each leaf's leading axis) over x (S, D)."""
     eps = m.get("norm_eps", 1e-5)
     di, gn, h, cd = _sizes(m)
     p_, n_, g_, w_ = m["ssm_headdim"], m["ssm_state"], m["ssm_ngroups"], m["d_conv"]
-    s = tokens.shape[0]
-    x = params["embed"]["tokens"][tokens].astype(F32)
+    s = x.shape[0]
 
     def layer(x, lp):
         mp = {k: v.astype(F32) for k, v in lp["mixer"].items()}
@@ -96,8 +101,13 @@ def hidden(params, tokens, m: Dict[str, Any], quant=None):
         y = rms_norm(y * jax.nn.silu(z), mp["norm_w"], eps)
         return x + mm(y, lp["mixer"]["out_proj"], quant), None
 
-    x, _ = jax.lax.scan(layer, x, params["stack"]["ssm"])
-    return rms_norm(x, params["final_norm"]["w"], eps)
+    x, _ = jax.lax.scan(layer, x, stack["ssm"])
+    return x
+
+
+def final(params, x, m: Dict[str, Any]):
+    """The last layer's output -> final-normed hidden states (S, D)."""
+    return rms_norm(x, params["final_norm"]["w"], m.get("norm_eps", 1e-5))
 
 
 def _per_token(m):

@@ -3,8 +3,9 @@
   python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 From the root of a checkout. One process, no children. It exits non-zero,
-printing no result, off a TPU, with interpreted Pallas kernels, or with
-fewer chips than the cell asks for. The last line of standard output is one
+printing no result, off a TPU, with interpreted Pallas kernels, with fewer
+chips than the cell asks for, or, before set-up, for a gang cell whose gang
+or events break ``spec.cell``'s rules. The last line of standard output is one
 JSON object: ``correct``, ``attempted``, ``failed``, ``metrics`` (the
 cell's end-to-end metrics, or with ``--trace 1`` its per-layer metrics),
 ``device``, with ``--trace 1`` ``breakdown``, and last ``check``: each
@@ -43,8 +44,11 @@ def main(argv=None) -> int:
                          f"known: {sorted(cells)}")
     harness.configure_jax()
     device = harness.require_chip(cells[args.workload]["chips"])
-    result = harness.run(args.workload, args.seed, args.seconds, bool(args.trace),
-                         T_START, device)
+    try:
+        result = harness.run(args.workload, args.seed, args.seconds, bool(args.trace),
+                             T_START, device)
+    except spec.CellError as e:
+        raise SystemExit(f"chipbench: {e}")
     for name, n in result["check"].items():
         print(f"check {name} {n['value']!r} limit {n['limit']!r}", file=sys.stderr)
     sys.stderr.flush()

@@ -79,13 +79,11 @@ def test_fp8_control_fails_the_limit(name):
     """The reference in fp8 in the program's place reads above the limit on
     every seed tried."""
     cell = spec.cell(name, DATA)
-    cs = cell["config_spec"]
     limit = cell["check"][check.GAP]
     for seed in (3, 4, 5):
         w, _, served, _ = harness.serve(cell, seed, 1.5, None, time.perf_counter())
         pairs = [served[i] for i in check.sample(served, cell["check"]["requests"], seed)]
-        ref = check.Reference(spec.reference(cs["reference"]), cs["model"],
-                              harness.make_params(cell, seed))
+        ref = harness.reference(cell, seed)
         assert check.widest_gap(ref, pairs, cell["max_seq"], control=True) > limit
         assert check.widest_gap(ref, pairs, cell["max_seq"]) <= limit
 
@@ -96,3 +94,42 @@ def test_sample_takes_the_longest_and_is_seeded():
     assert a[0] == 1 and len(a) == 3 and a == check.sample(served, 3, 11)
     assert sorted(check.sample(served, 10, 11)) == list(range(6))
     assert check.sample([], 3, 1) == []
+    # requests carried across a gang's resize come after the draw, once each
+    extra = [i for i in range(6) if i not in a][:2]
+    assert check.sample(served, 3, 11, extra + a) == a + extra
+
+
+@pytest.fixture(scope="module", params=CELLS)
+def served_sample(request):
+    """A cell, its weights and a seeded sample of what a run served."""
+    cell = spec.cell(request.param, DATA)
+    seed = 2**31 + 79
+    _, _, served, _ = harness.serve(cell, seed, 1.5, None, time.perf_counter())
+    pairs = [served[i] for i in check.sample(served, cell["check"]["requests"], seed)]
+    return cell, harness.make_params(cell, seed), pairs
+
+
+@pytest.mark.parametrize("control", [False, True])
+@pytest.mark.parametrize("block", ["every", 1])
+def test_blocked_reference_reads_the_whole_models_gaps(served_sample, block, control):
+    """On the CPU bit for bit, in one block of every layer or in blocks of
+    one layer, against the whole model as one program (weights on one
+    device run as that)."""
+    cell, params, pairs = served_sample
+    cs = cell["config_spec"]
+    ref_module = spec.reference(cs["reference"])
+    whole = check.Reference(ref_module, cs["model"], params)
+    ref = check.Reference(ref_module, cs["model"], params,
+                          cs["model"]["n_layers"] if block == "every" else block)
+    assert whole.block is None
+    for prompt, tokens in pairs:
+        assert np.array_equal(ref.gaps(prompt, tokens, cell["max_seq"], control),
+                              whole.gaps(prompt, tokens, cell["max_seq"], control))
+
+
+def test_block_size_divides_the_layers_and_fits_the_budget():
+    stack = {"w": jnp.zeros((12, 256, 256), jnp.bfloat16)}      # 256 KiB a layer in float32
+    assert check.block_layers(stack, 12, 2**20) == 4
+    assert check.block_layers(stack, 12, 5 * 2**18) == 4        # 5 fit; 4 divides 12
+    assert check.block_layers(stack, 12, 1) == 1
+    assert check.block_layers(stack, 12, 2**40) == 12

@@ -87,7 +87,15 @@ def init_spec(m):
     return {}
 
 
-def hidden(params, tokens, m, quant=None):
+def embed(params, tokens, m):
+    raise NotImplementedError
+
+
+def layers(stack, x, m, quant=None):
+    raise NotImplementedError
+
+
+def final(params, x, m):
     raise NotImplementedError
 
 

@@ -70,7 +70,8 @@ def test_added_files_are_found_by_name_with_no_edit(tmp_path):
 
 
 def test_every_committed_reference_gives_the_four_functions():
-    assert spec.REFERENCE_API == ("init_spec", "hidden", "prefill_flops", "decode_flops")
+    assert spec.REFERENCE_API == ("init_spec", "embed", "layers", "final", "prefill_flops",
+                                  "decode_flops")
     for c in spec.benchmark()["configs"]:
         cfg = json.loads((spec.CHECKOUT / c["file"]).read_text())
         ref = spec.reference(cfg["reference"])

@@ -1,6 +1,8 @@
 """The trace reduction on small traces recorded on a TPU v5e by
 record_trace.py (a 0.4 s span of each committed cell)."""
 import gzip
+import hashlib
+import json
 import re
 
 import pytest
@@ -104,3 +106,66 @@ def test_idle_gaps_carry_host_span_labels(traced):
     assert [s for _, s in red.gaps] == sorted((s for _, s in red.gaps), reverse=True)
     assert sum(trace_reduce.idle_by_span(red).values()) == pytest.approx(
         red.window_s - red.busy_s, abs=1e-6)
+
+
+# The parent commit's reduction of each committed trace (``reduce_profile``
+# before it took device ids): busy and window seconds, and a digest of every
+# field of the reduction.
+GOLDEN = {
+    "mamba2-2.7b.chat-burst": (0.6758191210000001, 0.749778469,
+                               "a7c8a0959c8df3164082535d5c7b30c45168bb54ffc7a72f214279ca143a6fab"),
+    "qwen2.5-3b.batch-gen.spans": (0.315746146, 0.362738436,
+                                   "fa5578fa028779f64e2cf568d524d34c978836bca6f7ac4599567381a4a17383"),
+    "qwen2.5-3b.doc-faas": (0.28126703000000003, 0.308421005,
+                            "01b2cd9b5a4ce6a24d8f84eda2d0d2f0d9fe186d935703e9b02631782d648eeb"),
+}
+
+
+def _digest(red):
+    blob = json.dumps([red.window_s, red.busy_s, red.n_devices, sorted(red.module_s.items()),
+                       sorted(red.module_n.items()), sorted(red.op_s.items()), red.gaps,
+                       sorted([list(k), v] for k, v in red.op_in_s.items())])
+    return hashlib.sha256(blob.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("devices", [None, [0]])
+@pytest.mark.parametrize("cell", sorted(GOLDEN))
+def test_one_plane_reduction_is_unchanged(cell, devices):
+    red = trace_reduce.reduce_profile(_profile(cell), devices)
+    assert (red.busy_s, red.window_s, _digest(red)) == GOLDEN[cell]
+
+
+class _Plane:
+    def __init__(self, name, lines):
+        self.name = name
+        self.lines = lines
+
+
+def _gang_profile(cell):
+    """The committed one-chip trace as a four-chip gang's that shrank to
+    two: devices 0 and 1 run the same programs, 2 and 3 were handed back
+    and run nothing."""
+    prof = _profile(cell)
+    planes = {p.name: _Plane(p.name, list(p.lines)) for p in prof.planes}
+    dev = planes.pop("/device:TPU:0")
+    gang = [_Plane(f"/device:TPU:{i}", dev.lines if i < 2 else []) for i in range(4)]
+    return type("Profile", (), {"planes": list(planes.values()) + gang[::-1]})()
+
+
+def test_gang_trace_reduces_over_its_member_planes():
+    cell = "qwen2.5-3b.doc-faas"
+    one = trace_reduce.reduce_profile(_profile(cell))
+    prof = _gang_profile(cell)
+    members = trace_reduce.reduce_profile(prof, [0, 1])
+    assert members.n_devices == 2
+    assert members.busy_s == pytest.approx(one.busy_s)
+    assert members.idle_share == pytest.approx(one.idle_share)
+    assert members.module_n == {k: 2 * n for k, n in one.module_n.items()}
+    assert members.module_s == pytest.approx({k: 2 * t for k, t in one.module_s.items()})
+    assert sum(t for _, t in members.gaps) == pytest.approx(2 * sum(t for _, t in one.gaps))
+    assert programs.classify(members.module_n) == programs.classify(one.module_n)
+    every = trace_reduce.reduce_profile(prof)
+    assert every.n_devices == 4
+    assert every.idle_share == pytest.approx((1 + one.idle_share) / 2)
+    with pytest.raises(ValueError, match=r"no plane for devices \[5\]"):
+        trace_reduce.reduce_profile(prof, [0, 5])

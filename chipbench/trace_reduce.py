@@ -10,6 +10,11 @@ the operations of its body, so container operations are left out of the
 busy union and the per-operation times.
 Host spans are the ``TraceAnnotation`` events on the host plane; the
 harness wraps the whole traced window in a span named ``window``.
+
+A gang's trace holds a plane for every chip of the host, those the gang
+handed back at an event among them, which run nothing. Given the device ids
+the gang's mesh spans over the traced span, the reduction reads those planes
+only; given none, every device plane (a one-chip cell's one plane).
 """
 from __future__ import annotations
 
@@ -19,9 +24,9 @@ import glob
 import re
 from collections import defaultdict
 from pathlib import Path
-from typing import Dict, List, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 # operations whose events span the operations of their body
 CONTAINERS = ("%while", "%conditional", "%call")
 HOST_PLANE = "/host:CPU"
@@ -75,11 +80,12 @@ def _clip(intervals, lo, hi):
     return [(max(s, lo), min(e, hi)) for s, e in intervals if e > lo and s < hi]
 
 
-def reduce_profile(profile) -> Reduction:
-    """Reduce a ``jax.profiler.ProfileData``."""
+def reduce_profile(profile, devices: Optional[Sequence[int]] = None) -> Reduction:
+    """Reduce a ``jax.profiler.ProfileData`` over the planes of ``devices``
+    (device ids), or over every device plane."""
     host_spans: List[Tuple[int, int, str]] = []
     window = None
-    devices = []
+    planes: List[Tuple[int, Any]] = []
     for plane in profile.planes:
         if plane.name == HOST_PLANE:
             for line in plane.lines:
@@ -89,11 +95,16 @@ def reduce_profile(profile) -> Reduction:
                     elif ev.name in HOST_SPANS:
                         host_spans.append((int(ev.start_ns), int(ev.end_ns), ev.name))
         elif DEVICE_PLANE.match(plane.name):
-            devices.append(plane)
+            planes.append((int(DEVICE_PLANE.match(plane.name).group(1)), plane))
     if window is None:
         raise ValueError(f"trace holds no host span named {WINDOW_SPAN!r}")
-    if not devices:
+    if not planes:
         raise ValueError("trace holds no /device:TPU:<n> plane")
+    if devices is not None:
+        missing = sorted(set(devices) - {i for i, _ in planes})
+        if missing:
+            raise ValueError(f"trace holds no plane for devices {missing}")
+        planes = [(i, plane) for i, plane in planes if i in devices]
     lo, hi = window
     module_s: Dict[str, float] = defaultdict(float)
     module_n: Dict[str, int] = defaultdict(int)
@@ -102,7 +113,7 @@ def reduce_profile(profile) -> Reduction:
     gaps: List[Tuple[str, float]] = []
     host_spans.sort()
     op_in_s: Dict[Tuple[str, str], float] = defaultdict(float)
-    for plane in devices:
+    for _, plane in planes:
         ops: List[Tuple[int, int]] = []
         runs: List[Tuple[int, int, str]] = []
         lines = {line.name: line for line in plane.lines}
@@ -130,8 +141,8 @@ def reduce_profile(profile) -> Reduction:
             if ge > gs:
                 gaps.append((_label(host_spans, (gs + ge) // 2), (ge - gs) * 1e-9))
     gaps.sort(key=lambda g: -g[1])
-    return Reduction(window_s=(hi - lo) * 1e-9, busy_s=busy_total / len(devices),
-                     n_devices=len(devices), module_s=dict(module_s),
+    return Reduction(window_s=(hi - lo) * 1e-9, busy_s=busy_total / len(planes),
+                     n_devices=len(planes), module_s=dict(module_s),
                      module_n=dict(module_n), op_s=dict(op_s), gaps=gaps,
                      op_in_s=dict(op_in_s))
 
@@ -145,9 +156,9 @@ def _label(spans: List[Tuple[int, int, str]], t: int) -> str:
     return NO_SPAN
 
 
-def reduce_dir(trace_dir: Path) -> Reduction:
+def reduce_dir(trace_dir: Path, devices: Optional[Sequence[int]] = None) -> Reduction:
     from jax.profiler import ProfileData
-    return reduce_profile(ProfileData.from_file(str(find_xplane(trace_dir))))
+    return reduce_profile(ProfileData.from_file(str(find_xplane(trace_dir))), devices)
 
 
 def idle_by_span(red: Reduction) -> Dict[str, float]:
