@@ -47,10 +47,11 @@ SEED = 0
 SCENARIO_MINUTES = 5.0   # simulated; about 150 arrivals at 0.5 qps
 SCENARIO_QPS = 0.5
 # Prefill logits, relative L2 over the vocabulary. Both policies run bf16
-# activations but round at different points (the flash kernel keeps the
-# softmax weights in f32 through the PV product, the reference rounds them
-# to bf16; the rmsnorm kernel scales in f32). Measured on a TPU v5e with this
-# prompt and seed: each policy lies 1.3e-2 to 1.4e-2 from an f32-activation
+# activations but round at different points (the flash kernel divides by the
+# softmax sum after the PV product, in f32, the reference before it, in bf16;
+# the rmsnorm kernel scales in f32). Measured on a TPU v5e with this prompt
+# and seed, when the kernel still fed the PV product f32 softmax weights:
+# each policy lies 1.3e-2 to 1.4e-2 from an f32-activation
 # reference (matmul precision "highest") and 1.6e-2 from the other. A wrong
 # mask, head mapping or scale is an O(1) error. So the policies must agree
 # within 4e-2, and the kernel policy may be at most 1.5x as far from the f32

@@ -50,9 +50,8 @@ def default_interpret() -> bool:
 
 
 def flash_attention_op(q, k, v, *, causal=True, window=None, scale=None,
-                       block_q=128, block_k=128, interpret=None):
+                       interpret=None):
     return flash_attention(q, k, v, causal=causal, window=window, scale=scale,
-                           block_q=block_q, block_k=block_k,
                            interpret=_default_interpret() if interpret is None else interpret)
 
 

@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (default_tiles, flash_attention,
+                                           kv_block, kv_range)
 from repro.kernels.moe_gmm import moe_gmm
 from repro.kernels.ops import moe_gmm_capacity, tile_experts_for_capacity
 from repro.kernels.rmsnorm import rmsnorm
@@ -30,6 +31,9 @@ def _tol(dtype):
     (2, 2, 1, 100, 100, 32, True, None),      # non-multiple seq (padding path)
     (1, 16, 8, 128, 128, 128, True, None),    # internlm2-like head geometry
     (1, 2, 2, 512, 512, 80, True, None),      # zamba2 head_dim=80
+    (1, 16, 2, 1000, 1000, 128, True, None),  # qwen2.5-3b, no tile multiple
+    (1, 16, 2, 1537, 1537, 128, True, None),  # qwen2.5-3b, odd length
+    (1, 16, 2, 1000, 1000, 128, True, 300),   # G=8 with a sliding window
 ])
 def test_flash_attention_matches_ref(b, h, kv, sq, sk, d, causal, window, dtype):
     ks = jax.random.split(RNG, 3)
@@ -64,9 +68,58 @@ def test_flash_attention_block_shape_invariance():
     k = jax.random.normal(ks[1], (1, 2, 256, 64))
     v = jax.random.normal(ks[2], (1, 2, 256, 64))
     outs = [flash_attention(q, k, v, block_q=bq, block_k=bk, interpret=True)
-            for bq, bk in [(64, 64), (128, 64), (64, 128), (256, 256)]]
+            for bq, bk in [(64, 64), (128, 64), (64, 128), (256, 256),
+                           (None, None)]]
     for o in outs[1:]:
         np.testing.assert_allclose(o, outs[0], atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("h,kv,s,causal,window", [
+    (16, 2, 1000, True, None),     # qwen2.5-3b prefill, no tile multiple
+    (16, 2, 777, True, 300),       # G=8 with a sliding window
+    (4, 4, 300, False, None),      # bidirectional, padded keys
+])
+def test_flash_attention_default_tiles_match_small_tiles(h, kv, s, causal,
+                                                         window):
+    """The shape-chosen tiles compute what explicit small tiles do."""
+    assert default_tiles(s, s, h // kv) != (64, 128)
+    ks = jax.random.split(RNG, 3)
+    q = jax.random.normal(ks[0], (1, h, s, 32))
+    k = jax.random.normal(ks[1], (1, kv, s, 32))
+    v = jax.random.normal(ks[2], (1, kv, s, 32))
+    small = flash_attention(q, k, v, causal=causal, window=window,
+                            block_q=64, block_k=128, interpret=True)
+    auto = flash_attention(q, k, v, causal=causal, window=window,
+                           interpret=True)
+    np.testing.assert_allclose(auto, small, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("sq,bq,bk,causal,window", [
+    (1000, 256, 512, True, None),
+    (1537, 224, 512, True, None),
+    (2048, 128, 256, True, 300),
+    (2048, 256, 128, True, 1000),
+    (1024, 64, 128, False, 200),
+])
+def test_flash_attention_skipped_steps_fetch_nothing(sq, bq, bk, causal,
+                                                     window):
+    """Walk the grid in its order: a step that computes gets its own k
+    block, and a skipped step repeats the block index of the step before
+    it, so the pipeline issues no DMA for it."""
+    nq, nk = -(-sq // bq), -(-sq // bk)
+    kw = dict(block_q=bq, block_k=bk, nk=nk, causal=causal, window=window)
+    prev, skipped = None, 0
+    for iq in range(nq):
+        lo, hi = (int(x) for x in kv_range(iq, **kw))
+        for ik in range(nk):
+            idx = int(kv_block(iq, ik, **kw))
+            if lo <= ik <= hi:
+                assert idx == ik, (iq, ik, idx)
+            else:
+                skipped += 1
+                assert idx == prev, (iq, ik, idx, prev)
+            prev = idx
+    assert skipped > 0
 
 
 # --- rmsnorm -------------------------------------------------------------------

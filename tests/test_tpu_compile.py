@@ -65,12 +65,20 @@ def _compile_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _flash(s):
+def _flash(s, length=256):
     from repro.kernels.flash_attention import flash_attention
     bf = jnp.bfloat16    # qwen2.5-3b prefill: 16 query / 2 KV heads of 128
     return (lambda q, k, v: flash_attention(q, k, v, causal=True),
-            s((1, 16, 256, 128), bf), s((1, 2, 256, 128), bf),
-            s((1, 2, 256, 128), bf))
+            s((1, 16, length, 128), bf), s((1, 2, length, 128), bf),
+            s((1, 2, length, 128), bf))
+
+
+def _flash_1024(s):
+    return _flash(s, 1024)
+
+
+def _flash_2048(s):
+    return _flash(s, 2048)
 
 
 def _rmsnorm(s):
@@ -105,8 +113,10 @@ def _ssd(s):
             s((1, 512, 1, 128), bf))
 
 
-@pytest.mark.parametrize("case", [_flash, _rmsnorm, _moe_gmm, _paged, _ssd],
-                         ids=["flash", "rmsnorm", "moe_gmm", "paged", "ssd"])
+@pytest.mark.parametrize("case", [_flash, _rmsnorm, _moe_gmm, _paged, _ssd,
+                                  _flash_1024, _flash_2048],
+                         ids=["flash", "rmsnorm", "moe_gmm", "paged", "ssd",
+                              "flash_1024", "flash_2048"])
 def test_kernel_compiles_for_v5e(one_chip, case):
     fn, *args = case(functools.partial(_sds, one_chip))
     assert "tpu_custom_call" in _compile_text(fn, *args)
