@@ -70,7 +70,6 @@ def main() -> None:
     from benchmarks import paper_benches as PB
     from benchmarks import reliability as RL
     from benchmarks import routing as RT
-    from benchmarks import serving_batching as SB
     from benchmarks import serving_matrix as SM
 
     if args.smoke:
@@ -93,9 +92,6 @@ def main() -> None:
         # the storm needs a few window generations before the goodput gap
         # stabilises; never run the grid shorter than 30 sim-minutes
         "elastic": lambda: ES.bench_elastic(max(grid, 30 * 60.0)),
-        "serving": lambda: SB.bench_serving(
-            n_requests=8 if args.smoke else 16, n_new=8 if args.smoke else 16,
-            repeats=2 if args.smoke else 3),
         "paged_kv": lambda: PK.bench_paged_kv(
             n_requests=12 if args.smoke else 24,
             kernel_requests=4 if args.smoke else 6),

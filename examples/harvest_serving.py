@@ -1,14 +1,14 @@
 """The paper's scenario end-to-end WITH REAL JAX EXECUTION: a cluster's idle
 windows host pilot-job invokers that serve actual model inference (bounded
 decode on a reduced qwen2.5 config). Virtual time advances by the measured
-wall-clock of each real generate() call.
+wall-clock of each request's real decode.
 
 This is HPC-Whisk as a serving system: dynamic registration, fast-lane
 hand-off on preemption, Alg. 1 commercial fallback — with the FaaS function
 being a bounded decode. Concurrent in-flight requests on an invoker are
 aggregated onto one ContinuousEngine (continuous batching: per-slot decode
 positions, one batched decode per token wave) via the ``batched-serving``
-executor; ``--sequential`` keeps the old one-generate-per-request path.
+executor.
 
 Run: PYTHONPATH=src python examples/harvest_serving.py [--minutes 20]
 """
@@ -21,9 +21,8 @@ from repro.configs import get_config
 from repro.core import CommercialBackend, FaaSWrapper
 from repro.models import init_params
 from repro.platform import (BatchedServingExecutor, Platform, ScenarioConfig,
-                            SchedulingSection, ServingExecutor, TraceSection,
-                            WorkloadSection)
-from repro.serving.engine import ContinuousEngine, ServingEngine
+                            SchedulingSection, TraceSection, WorkloadSection)
+from repro.serving.engine import ContinuousEngine
 
 
 def main():
@@ -31,21 +30,15 @@ def main():
     ap.add_argument("--minutes", type=float, default=20.0)
     ap.add_argument("--qps", type=float, default=0.5)
     ap.add_argument("--slots", type=int, default=4)
-    ap.add_argument("--sequential", action="store_true",
-                    help="one generate() per request instead of continuous batching")
     args = ap.parse_args()
     duration = args.minutes * 60.0
 
     print("loading model (the invoker warm-up the paper measures)...")
     cfg = get_config("qwen2.5-3b", smoke=True)
     params = init_params(jax.random.PRNGKey(0), cfg)
-    if args.sequential:
-        executor = ServingExecutor(ServingEngine(cfg, params, max_seq=64),
-                                   prompt_len=16, n_new=8)
-    else:
-        executor = BatchedServingExecutor(
-            ContinuousEngine(cfg, params, n_slots=args.slots, max_seq=64),
-            prompt_len=16, n_new=8)
+    executor = BatchedServingExecutor(
+        ContinuousEngine(cfg, params, n_slots=args.slots, max_seq=64),
+        prompt_len=16, n_new=8)
 
     sc = ScenarioConfig(
         name="harvest_serving", duration=duration, seed=0,
@@ -70,11 +63,10 @@ def main():
     if rts:
         print(f"  response p50      : {np.percentile(rts, 50):.3f}s "
               f"(REAL decode wall-time inside virtual time)")
-    if not args.sequential:
-        eng = executor.engine
-        print(f"  batched decode    : {eng.n_decode_steps} waves, "
-              f"occupancy {eng.occupancy:.0%}" if eng.n_decode_steps else
-              "  batched decode    : (no batched waves)")
+    eng = executor.engine
+    print(f"  batched decode    : {eng.n_decode_steps} waves, "
+          f"occupancy {eng.occupancy:.0%}" if eng.n_decode_steps else
+          "  batched decode    : (no batched waves)")
     print(f"  executed tokens   : ~{len(done) * 8} real greedy-decoded tokens")
 
 

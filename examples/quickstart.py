@@ -11,14 +11,14 @@ Run: PYTHONPATH=src python examples/quickstart.py
 """
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
 from repro.data.pipeline import DataPipeline
 from repro.platform import Platform, ScenarioConfig, SchedulingSection, \
     WorkloadSection
 from repro.models import init_params
-from repro.serving.engine import ServingEngine
+from repro.serving.batching import GenRequest
+from repro.serving.engine import ContinuousEngine
 from repro.training.optimizer import OptimizerConfig, init_opt_state
 from repro.training.train_step import make_train_step
 
@@ -33,9 +33,10 @@ batch = {k: jnp.asarray(v) for k, v in pipe.next_batch().items()}
 params, opt, metrics = step(params, opt, batch)
 print(f"train step: loss={float(metrics['loss']):.3f}")
 
-engine = ServingEngine(cfg, params, max_seq=48)
-out = engine.generate(np.ones((1, 8), np.int32), n_new=8)
-print(f"decode: generated tokens {out[0].tolist()}")
+engine = ContinuousEngine(cfg, params, n_slots=1, max_seq=48)
+engine.add(GenRequest(id=0, prompt=[1] * 8, max_new=8))
+(done,) = engine.run()
+print(f"decode: generated tokens {done.generated}")
 
 print("\n== 2. harvest layer (the paper) ==")
 sc = ScenarioConfig(name="quickstart", duration=3600.0, seed=0,

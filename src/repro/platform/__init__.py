@@ -19,8 +19,7 @@ from repro.platform.routers import (DeadlineAwareRouter, HashRouter,
                                     LeastLoadedRouter, LocalityRouter)
 from repro.platform.scalers import AdaptiveJobManager, JobManager
 from repro.platform.sources import SuiteLoad, UniformLoad
-from repro.platform.executors import (BatchedServingExecutor, ServingExecutor,
-                                      SimExecutor)
+from repro.platform.executors import BatchedServingExecutor, SimExecutor
 from repro.platform.elastic import (ElasticGangInvoker, ElasticServingExecutor,
                                     GangMember, GangPool)
 from repro.platform import admission as _admission  # noqa: F401 (registers)
@@ -38,7 +37,7 @@ __all__ = [
     "DeadlineAwareRouter", "RetryPolicy",
     "JobManager", "AdaptiveJobManager",
     "UniformLoad", "SuiteLoad",
-    "SimExecutor", "ServingExecutor", "BatchedServingExecutor",
+    "SimExecutor", "BatchedServingExecutor",
     "GangMember", "ElasticGangInvoker", "GangPool", "ElasticServingExecutor",
     "HarvestConfig", "HarvestResult", "HarvestRuntime", "Platform",
     "nan_to_none",

@@ -3,26 +3,24 @@
 Sim-only and real-JAX runs share one construction path — the scenario's
 ``platform.executor`` key resolves here, and the invoker calls whatever it
 gets the same way. :class:`SimExecutor` returns the request's nominal service
-time; :class:`ServingExecutor` performs a real bounded decode on a
-:class:`repro.serving.engine.ServingEngine` and returns measured wall
-seconds, which advance virtual time (the scheduling layer is oblivious —
-the paper's Sec. V-D setup).
+time.
 
-:class:`BatchedServingExecutor` (registry key ``batched-serving``) is the
-continuous-batching variant: it exposes ``run_batch`` so the invoker hands it
-every request it admits in one pull, and all of them decode together on a
+:class:`BatchedServingExecutor` (registry key ``batched-serving``) performs
+real decodes and returns measured wall seconds, which advance virtual time
+(the scheduling layer is oblivious — the paper's Sec. V-D setup). It exposes
+``run_batch`` so the invoker hands it every request it admits in one pull,
+and all of them decode together on a
 :class:`repro.serving.engine.ContinuousEngine` — one batched decode per token
-wave instead of one full generate per request. Each request is charged its
-own completion latency inside the batch, so virtual time sees the real
-(shorter) wall clock the invoker spent. ``drain()`` parks partial
-generations keyed by request id; a resubmitted request resumes its decode.
+wave. Each request is charged its own completion latency inside the batch,
+so virtual time sees the real wall clock the invoker spent; a single request
+is a batch of one. ``drain()`` parks partial generations keyed by request
+id; a resubmitted request resumes its decode.
 
 JAX (and the model zoo) are imported lazily inside the serving factories,
 so pure-simulation scenarios never pay the accelerator-stack import.
 """
 from __future__ import annotations
 
-import time
 import zlib
 from typing import Dict, List, TYPE_CHECKING
 
@@ -80,28 +78,9 @@ class SimExecutor:
         return req.exec_time
 
 
-class ServingExecutor:
-    """Real JAX execution: a bounded ``generate`` call on a serving engine;
-    the function name seeds the prompt (stable digest) so each FaaS function
-    is a distinct, reproducible decode."""
-
-    def __init__(self, engine, prompt_len: int = 16, n_new: int = 8):
-        self.engine = engine
-        self.prompt_len = prompt_len
-        self.n_new = n_new
-
-    def __call__(self, req: "Request") -> float:
-        prompt = np.asarray([prompt_for_fn(req.fn, self.engine.cfg.vocab_size,
-                                           self.prompt_len)], np.int32)
-        t0 = time.perf_counter()
-        self.engine.generate(prompt, self.n_new)
-        return time.perf_counter() - t0
-
-
 class BatchedServingExecutor:
     """Continuous-batching execution: concurrent in-flight requests on an
-    invoker share one :class:`ContinuousEngine` instead of serializing
-    through per-request ``generate`` calls.
+    invoker share one :class:`ContinuousEngine`.
 
     The invoker detects ``run_batch`` and hands over every request admitted
     in one pull loop; per-request cost is the request's real completion
@@ -209,7 +188,7 @@ def build_sim(platform: "Platform", **params) -> SimExecutor:
     return SimExecutor(**params)
 
 
-def _smoke_engine(arch: str, init_seed: int, max_seq: int, continuous: bool,
+def _smoke_engine(arch: str, init_seed: int, max_seq: int,
                   paged: bool = False, kernel_impls="reference",
                   **engine_params):
     import jax  # deferred: only real-JAX scenarios pay this import
@@ -217,16 +196,13 @@ def _smoke_engine(arch: str, init_seed: int, max_seq: int, continuous: bool,
     from repro.configs import get_config
     from repro.configs.base import with_kernel_impls
     from repro.models import init_params
-    from repro.serving.engine import (ContinuousEngine,
-                                      PagedContinuousEngine, ServingEngine)
+    from repro.serving.engine import ContinuousEngine, PagedContinuousEngine
     cfg = get_config(arch, smoke=True)
     if kernel_impls != "reference":
         cfg = with_kernel_impls(cfg, kernel_impls)
     model_params = init_params(jax.random.PRNGKey(init_seed), cfg)
-    if continuous:
-        cls = PagedContinuousEngine if paged else ContinuousEngine
-        return cls(cfg, model_params, max_seq=max_seq, **engine_params)
-    return ServingEngine(cfg, model_params, max_seq=max_seq)
+    cls = PagedContinuousEngine if paged else ContinuousEngine
+    return cls(cfg, model_params, max_seq=max_seq, **engine_params)
 
 
 def _scenario_model_knobs(platform: "Platform", arch, kernel_impls):
@@ -238,17 +214,6 @@ def _scenario_model_knobs(platform: "Platform", arch, kernel_impls):
     if kernel_impls is None:
         kernel_impls = getattr(sc, "kernel_impls", "reference") or "reference"
     return arch, kernel_impls
-
-
-@register("executor", "serving")
-def build_serving(platform: "Platform", *, engine=None, arch: str = None,
-                  max_seq: int = 64, init_seed: int = 0, kernel_impls=None,
-                  **params) -> ServingExecutor:
-    arch, kernel_impls = _scenario_model_knobs(platform, arch, kernel_impls)
-    if engine is None:
-        engine = _smoke_engine(arch, init_seed, max_seq, continuous=False,
-                               kernel_impls=kernel_impls)
-    return ServingExecutor(engine, **params)
 
 
 _KV_GAUGES = ("blocks_in_use", "blocks_high_water", "bytes_in_use",
@@ -293,7 +258,7 @@ def build_batched_serving(platform: "Platform", *, engine=None,
     if engine is None:
         paged_kw = (dict(block_size=block_size, n_blocks=n_blocks, attn=attn)
                     if kv_layout == "paged" else {})
-        engine = _smoke_engine(arch, init_seed, max_seq, continuous=True,
+        engine = _smoke_engine(arch, init_seed, max_seq,
                                paged=(kv_layout == "paged"),
                                n_slots=n_slots, kernel_impls=kernel_impls,
                                **paged_kw)
@@ -309,6 +274,6 @@ def as_executor(obj):
     raise TypeError(f"executor override must be callable, got {type(obj)!r}")
 
 
-__all__ = ["SimExecutor", "ServingExecutor", "BatchedServingExecutor",
+__all__ = ["SimExecutor", "BatchedServingExecutor",
            "prompt_for_fn", "tenant_of", "tenant_prefix", "as_executor",
-           "build_sim", "build_serving", "build_batched_serving"]
+           "build_sim", "build_batched_serving"]

@@ -181,7 +181,7 @@ class Platform:
         """Construct a fully-wired platform from a declarative scenario.
         Keyword overrides (``windows``, ``trace_cfg``, ``executor``,
         ``suite``, ``slos``) inject pre-built objects where a registry key
-        is not expressive enough (e.g. a live ServingEngine executor)."""
+        is not expressive enough (e.g. an executor over a live engine)."""
         return cls(scenario, **overrides)
 
     def _count_workers(self, state: str) -> int:

@@ -1,5 +1,5 @@
-"""Serving runtime: sequential + continuous-batching engines over dense or
-block-paged KV caches.
+"""Serving runtime: the continuous-batching engine over dense or block-paged
+KV caches.
 
 Exports resolve lazily (PEP 562): ``batching``/``kvcache`` bookkeeping is
 importable without JAX (the fast-tier allocator fuzz tests rely on that),
@@ -18,7 +18,6 @@ _EXPORTS = {
     "OutOfBlocks": "repro.serving.kvcache",
     "PagedContinuousEngine": "repro.serving.engine",
     "PagedKVCache": "repro.serving.kvcache",
-    "ServingEngine": "repro.serving.engine",
     "SlotBatchState": "repro.serving.slot_state",
     "SlotBatcher": "repro.serving.batching",
     "find_batch_axes": "repro.serving.slot_state",
@@ -33,7 +32,6 @@ __all__ = [
     "OutOfBlocks",
     "PagedContinuousEngine",
     "PagedKVCache",
-    "ServingEngine",
     "SlotBatchState",
     "SlotBatcher",
     "find_batch_axes",

@@ -4,18 +4,14 @@ This is what an HPC-Whisk *invoker* hosts on a harvested slice: the engine is
 constructed once per pilot job (the warm-up cost the paper measures) and then
 serves seconds-long invocations (bounded generate calls) until SIGTERM.
 
-Two decode paths:
-
-:class:`ServingEngine`
-    run-to-completion ``generate`` on one request batch — the sequential
-    baseline, and still the scoring/integrity path.
-:class:`ContinuousEngine`
-    slot-based continuous batching: each arriving request is prefilled into a
-    free batch slot (its KV cache grafted into the live batch cache), every
-    active slot advances with ONE batched ``decode_step`` per token using a
-    per-slot position vector, and freed slots are refilled without stopping
-    the loop. ``drain()`` hands back partial generations for the fast-lane
-    requeue (PR 4's ``resubmit()``), which resume instead of restarting.
+:class:`ContinuousEngine` is the decode engine: slot-based continuous
+batching. Each arriving request is prefilled into a free batch slot (its KV
+cache grafted into the live batch cache), every active slot advances with
+ONE batched ``decode_step`` per token using a per-slot position vector, and
+freed slots are refilled without stopping the loop. ``drain()`` hands back
+partial generations for the fast-lane requeue (``resubmit()``), which resume
+instead of restarting. :class:`PagedContinuousEngine` runs the same request
+lifecycle over a block-paged KV cache.
 """
 from __future__ import annotations
 
@@ -45,8 +41,6 @@ def _pick(logits, vocab_size: int, temperature: float, rng):
     return nxt[:, None].astype(jnp.int32)
 
 
-_CACHE_BUCKET = 64  # sequential-path caches sized in buckets, not max_seq
-
 # Host spans (``jax.profiler.TraceAnnotation``) the continuous engines open
 # around their work, on the profiler's clock: an admission (stats ``req``,
 # ``slot``, ``ctx``) holding its context's prefill (``req``, ``ctx``), the
@@ -56,70 +50,6 @@ _CACHE_BUCKET = 64  # sequential-path caches sized in buckets, not max_seq
 SPANS = ADMIT, PREFILL, GRAFT, PICK, DECODE, EMIT = (
     "engine.admit", "engine.prefill", "engine.graft", "engine.pick",
     "engine.decode", "engine.emit")
-
-
-class ServingEngine:
-    def __init__(self, cfg: ModelConfig, params, max_seq: int = 512):
-        if not cfg.is_autoregressive:
-            raise ValueError(f"arch {cfg.arch_id!r} is encoder-only: it is "
-                             f"scored, not decoded")
-        self.cfg = cfg
-        self.params = params
-        self.max_seq = max_seq
-        self._prefill = jax.jit(functools.partial(model_mod.prefill, cfg=cfg))
-        self._decode = jax.jit(functools.partial(model_mod.decode_step, cfg=cfg))
-
-    def _grown_cache(self, cache, batch: int, seq_cap: Optional[int] = None):
-        """Pad a prefill cache up to ``(batch, seq_cap)``. ``seq_cap`` used
-        to be pinned at ``max_seq``, so every 24-token request reserved (and
-        paid allocation for) the full window; callers now pass the
-        bucket-rounded need (the bucket bounds jit retraces)."""
-        full = model_mod.init_cache(self.cfg, batch,
-                                    self.max_seq if seq_cap is None else seq_cap)
-
-        def graft(z, c):
-            if z.shape == c.shape:
-                return c.astype(z.dtype)
-            assert z.ndim == c.ndim, (z.shape, c.shape)
-            # pad EVERY mismatched axis (batch and sequence can both differ
-            # when a cache is grafted across request shapes), never shrink
-            pad = [(0, zi - ci) for zi, ci in zip(z.shape, c.shape)]
-            assert all(hi >= 0 for _, hi in pad), (z.shape, c.shape)
-            out = jnp.pad(c.astype(z.dtype), pad)
-            assert out.shape == z.shape, (out.shape, z.shape)
-            return out
-        return jax.tree.map(graft, full, cache)
-
-    def generate(self, tokens: np.ndarray, n_new: int,
-                 temperature: float = 0.0, seed: int = 0) -> np.ndarray:
-        """Greedy (or sampled) generation. tokens: (B, S) int32 prompt."""
-        b, s = tokens.shape
-        assert s + n_new <= self.max_seq, (s, n_new, self.max_seq)
-        seq_cap = min(self.max_seq, -(-(s + n_new) // _CACHE_BUCKET) * _CACHE_BUCKET)
-        logits, cache = self._prefill(self.params, {"tokens": jnp.asarray(tokens)})
-        cache = self._grown_cache(cache, b, seq_cap)
-        rng = jax.random.PRNGKey(seed)
-        # key hygiene: the root key is only ever split, never consumed — the
-        # first sample uses a subkey so tokens 0 and 1 are uncorrelated
-        rng, sub = jax.random.split(rng)
-        out = [self._pick(logits, temperature, sub)]
-        for i in range(1, n_new):
-            rng, sub = jax.random.split(rng)
-            logits, cache = self._decode(self.params, out[-1], cache,
-                                         jnp.int32(s + i - 1))
-            out.append(self._pick(logits, temperature, sub))
-        return np.concatenate([np.asarray(t) for t in out], axis=1)
-
-    def _pick(self, logits, temperature, rng):
-        return _pick(logits, self.cfg.vocab_size, temperature, rng)
-
-    def score(self, tokens: np.ndarray) -> float:
-        """Mean NLL of a token batch (used as a cheap integrity check when an
-        invoker re-registers after migration)."""
-        batch = {"tokens": jnp.asarray(tokens[:, :-1]),
-                 "labels": jnp.asarray(tokens[:, 1:])}
-        loss, _ = model_mod.loss_fn(self.params, batch, self.cfg)
-        return float(loss)
 
 
 class ContinuousEngine:
@@ -133,9 +63,9 @@ class ContinuousEngine:
     model's ``cache_spec`` declares. The engine is therefore
     architecture-agnostic: admission prefills the request context (prompt +
     any drained partial) at batch 1 and grafts the resulting state into this
-    request's batch row; the other rows keep decoding untouched.
-    Temperature-0 outputs are token-identical to the sequential
-    :meth:`ServingEngine.generate` path.
+    request's batch row; the other rows keep decoding untouched. A
+    request's temperature-0 stream does not depend on ``n_slots`` or on
+    what the other slots serve.
     """
 
     def __init__(self, cfg: ModelConfig, params, n_slots: int = 4,
@@ -323,8 +253,7 @@ class ContinuousEngine:
         ``{request id -> completion offset in wall seconds}`` (prefill
         included; a request can finish at admission). The finished requests
         stay on ``batcher.finished`` for the caller to consume. This is the
-        one timed loop both the batched executor and the serving benchmark
-        charge from."""
+        one timed loop the batched executor charges from."""
         t0 = time.perf_counter()
         finished_at: Dict[int, float] = {}
 
@@ -707,5 +636,5 @@ class PagedContinuousEngine(ContinuousEngine):
 
 
 # FaaS-request -> real-execution adaptation lives behind the platform's
-# Executor seam: see repro.platform.executors.ServingExecutor (sequential)
-# and BatchedServingExecutor (continuous batching, key "batched-serving").
+# Executor seam: see repro.platform.executors.BatchedServingExecutor
+# (key "batched-serving").

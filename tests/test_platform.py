@@ -28,7 +28,7 @@ def test_registry_resolves_bundled_components():
     assert {"static", "adaptive"} <= set(available("scaler"))
     assert {"none", "slo"} <= set(available("admission"))
     assert {"uniform", "suite"} <= set(available("workload"))
-    assert {"sim", "serving"} <= set(available("executor"))
+    assert {"sim", "batched-serving"} <= set(available("executor"))
     assert {"default", "burst"} <= set(available("suite"))
     assert {"none", "retry"} <= set(available("reliability"))
     assert resolve("router", "hash") is HashRouter

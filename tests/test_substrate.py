@@ -16,7 +16,8 @@ from repro.distributed.compression import dequantize, ef_compress, quantize
 from repro.distributed.sharding import batch_spec, param_specs
 from repro.launch.mesh import make_mesh
 from repro.models import init_params
-from repro.serving.engine import ServingEngine
+from repro.serving.batching import GenRequest
+from repro.serving.engine import ContinuousEngine
 from repro.training.optimizer import (OptimizerConfig, adamw_update,
                                       init_opt_state, schedule)
 from repro.training.train_step import make_train_step
@@ -162,20 +163,16 @@ def test_batch_spec_divisibility():
 
 # --- serving -----------------------------------------------------------------------------------
 def test_serving_engine_greedy_deterministic():
+    """The same prompt served twice (the second time into a slot its first
+    run left dirty) gives the same in-vocab tokens."""
     cfg = get_config("qwen2.5-3b", smoke=True)
     params = init_params(jax.random.PRNGKey(0), cfg)
-    eng = ServingEngine(cfg, params, max_seq=48)
-    prompt = np.ones((1, 8), np.int32) * 3
-    out1 = eng.generate(prompt, 8)
-    out2 = eng.generate(prompt, 8)
-    np.testing.assert_array_equal(out1, out2)
-    assert out1.shape == (1, 8)
-    assert (out1 >= 0).all() and (out1 < cfg.vocab_size).all()
-
-
-def test_serving_engine_score_finite():
-    cfg = get_config("mamba2-2.7b", smoke=True)
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    eng = ServingEngine(cfg, params, max_seq=64)
-    toks = np.ones((2, 33), np.int32)
-    assert np.isfinite(eng.score(toks))
+    eng = ContinuousEngine(cfg, params, n_slots=1, max_seq=48)
+    outs = []
+    for rid in range(2):
+        eng.add(GenRequest(id=rid, prompt=[3] * 8, max_new=8))
+        (done,) = eng.run()
+        outs.append(done.generated)
+    assert outs[0] == outs[1]
+    assert len(outs[0]) == 8
+    assert all(0 <= t < cfg.vocab_size for t in outs[0])

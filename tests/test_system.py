@@ -13,9 +13,9 @@ import pytest
 from repro.configs import get_config
 from repro.launch.train import TrainConfig, train
 from repro.models import init_params
-from repro.platform import (Platform, ScenarioConfig, SchedulingSection,
-                            ServingExecutor, TraceSection, WorkloadSection)
-from repro.serving.engine import ServingEngine
+from repro.platform import (BatchedServingExecutor, Platform, ScenarioConfig,
+                            SchedulingSection, TraceSection, WorkloadSection)
+from repro.serving.engine import ContinuousEngine
 
 pytestmark = pytest.mark.slow  # JAX tier: excluded from the fast core-sim run
 
@@ -27,12 +27,12 @@ def test_harvest_executes_real_jax_inference():
     virtual clock; everything accepted completes."""
     cfg = get_config("qwen2.5-3b", smoke=True)
     params = init_params(jax.random.PRNGKey(0), cfg)
-    engine = ServingEngine(cfg, params, max_seq=48)
+    engine = ContinuousEngine(cfg, params, n_slots=4, max_seq=48)
     sc = ScenarioConfig(duration=900.0, seed=0, trace=TraceSection(seed=4),
                         workload=WorkloadSection(qps=0.2, n_functions=4),
                         scheduling=SchedulingSection(model="fib"))
-    rt = Platform.build(sc, executor=ServingExecutor(engine, prompt_len=8,
-                                                     n_new=4))
+    rt = Platform.build(sc, executor=BatchedServingExecutor(
+        engine, prompt_len=8, n_new=4))
     res = rt.run()
     done = [r for r in res.requests if r.outcome == "success"]
     assert len(done) >= 1
